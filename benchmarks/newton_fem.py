@@ -32,6 +32,7 @@ import time
 
 import numpy as np
 
+from benchmarks.common import enable_compile_cache
 from benchmarks.solve_service import REGRESSION_TOL
 
 PARITY_ATOL = 1e-9
@@ -312,6 +313,7 @@ def main() -> None:
     ap.add_argument("--no-newton", dest="newton", action="store_false")
     ap.add_argument("--no-fem", dest="fem", action="store_false")
     args = ap.parse_args()
+    enable_compile_cache()
 
     doc = build_doc(smoke=args.smoke, seed=args.seed, repeats=args.repeats,
                     newton=args.newton, fem=args.fem)

@@ -6,7 +6,7 @@ numbers.  Default sizes are CPU-reduced; ``--full`` widens them.
 
 The sweeps run on the batched engine (:mod:`repro.core.engine`): each
 size/parameter class builds its netlists host-side, then errors come
-from one ``operating_point_batch`` (vmapped x64 DC solve) and settling
+from one ``operating_point_batch`` (fp64-refined DC solve) and settling
 times from one ``transient_batch`` (stacked-eig modal path) per class,
 instead of per-system Python loops.
 """

@@ -13,6 +13,8 @@ import argparse
 import json
 from pathlib import Path
 
+from benchmarks.common import enable_compile_cache
+
 
 RESULTS = Path(__file__).resolve().parents[1] / "results" / "perf"
 
@@ -179,6 +181,7 @@ def main():
     ap.add_argument("--exp", default="all",
                     help=f"one of {list(EXPERIMENTS)} or 'all'")
     args = ap.parse_args()
+    enable_compile_cache()
     RESULTS.mkdir(parents=True, exist_ok=True)
     names = list(EXPERIMENTS) if args.exp == "all" else [args.exp]
     for name in names:

@@ -13,6 +13,8 @@ import argparse
 import json
 from pathlib import Path
 
+from benchmarks.common import enable_compile_cache
+
 
 RESULTS = Path(__file__).resolve().parents[1] / "results" / "dryrun"
 
@@ -85,6 +87,7 @@ def main():
     ap.add_argument("--mesh", default="single_pod",
                     choices=["single_pod", "multi_pod", "both"])
     args = ap.parse_args()
+    enable_compile_cache()
     meshes = (["single_pod", "multi_pod"] if args.mesh == "both"
               else [args.mesh])
     for m in meshes:

@@ -4,8 +4,8 @@ Prints ``name,metric,value`` CSV rows.  ``--full`` reproduces the
 paper-scale sweeps (slow); the default is a reduced CPU-friendly pass.
 
 The figure sweeps run on the batched engine (``repro.core.engine``):
-each size/parameter class is one batched operating-point call (vmapped
-x64 solve) plus one batched settling call (stacked-eig modal path, or
+each size/parameter class is one batched operating-point call (fp64-refined
+DC solve) plus one batched settling call (stacked-eig modal path, or
 the matrix-free ELL sweep for ``tpu_complexity``), instead of
 per-system Python loops.
 
@@ -53,6 +53,8 @@ import argparse
 import json
 import sys
 import time
+
+from benchmarks.common import enable_compile_cache
 
 BENCH_SCHEMA = "bench_pr2.v1"
 
@@ -114,6 +116,7 @@ def main() -> None:
     ap.add_argument("--json-newton-fem", default="BENCH_pr8.json",
                     help="newton/fem baseline output path ('' to skip)")
     args = ap.parse_args()
+    enable_compile_cache()
 
     from benchmarks.common import emit
     from benchmarks.paper_figs import ALL

@@ -74,6 +74,8 @@ import time
 
 import numpy as np
 
+from benchmarks.common import enable_compile_cache
+
 PARITY_ATOL = 1e-9
 BENCH_SCHEMA = "bench_pr7.v1"
 PRECISION_SCHEMA = "bench_pr9.v1"
@@ -727,6 +729,7 @@ def main() -> None:
                     help="best-of-N timing repeats per point")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args()
+    enable_compile_cache()
 
     if args.precision:
         doc = build_precision_doc(smoke=args.smoke, seed=args.seed)

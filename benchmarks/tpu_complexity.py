@@ -60,7 +60,7 @@ import zlib
 
 import numpy as np
 
-from benchmarks.common import US, emit, stats
+from benchmarks.common import US, emit, enable_compile_cache, stats
 from repro.core import engine
 from repro.core.network import build_proposed
 
@@ -393,6 +393,7 @@ if __name__ == "__main__":
                     help="spectral-vs-eig slow-mode guard; exit 1 when "
                          "the ratio leaves [0.5, 2.0]")
     args = ap.parse_args()
+    enable_compile_cache()
     if args.parity:
         fails = parity_check()
         for f in fails:

@@ -26,6 +26,7 @@ from repro.analysis.rules import ALL_RULES
 from repro.analysis.runtime import (
     CompileWatch,
     SyncWatch,
+    host_callbacks,
     run_service_gate,
     sync_scope,
 )
@@ -39,6 +40,7 @@ __all__ = [
     "Rule",
     "SyncWatch",
     "diff_baseline",
+    "host_callbacks",
     "human_report",
     "is_suppressed",
     "json_report",

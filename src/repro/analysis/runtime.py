@@ -4,14 +4,15 @@ The static rules (:mod:`repro.analysis.rules`) claim two steady-state
 invariants the serving stack's throughput depends on; this module makes
 them falsifiable at run time:
 
-* **zero post-warmup compilations** — :class:`CompileWatch` wraps
-  ``jax._src.compiler.backend_compile`` (the single funnel every jit
-  lowering passes through) and records each XLA compilation with its
-  module name and optimized HLO text.  The HLO is inspected with the
-  roofline parser (:func:`repro.roofline.hlo_parse.host_callback_ops`)
-  so a hot-path executable smuggling a host callback (python callback
-  custom-calls, infeed/outfeed) is flagged even when the compile count
-  itself is legitimate warmup.
+* **zero post-warmup compilations** — :class:`CompileWatch` listens to
+  the public ``jax.monitoring`` event JAX records around every backend
+  compilation (:data:`BACKEND_COMPILE_EVENT`) and keeps each one's
+  function name.  :func:`host_callbacks` scans an executable's compiled
+  text (``.lower().compile().as_text()``) with the roofline parser
+  (:func:`repro.roofline.hlo_parse.host_callback_ops`), so a hot-path
+  executable smuggling a host callback (python callback custom-calls,
+  infeed/outfeed) is flagged even when the compile count itself is
+  legitimate warmup.
 * **zero dispatch-phase host syncs** — :class:`SyncWatch` counts host
   materializations of ``jax.Array`` values, attributed to the phase
   label the service declares via :func:`sync_scope` (``dispatch`` /
@@ -33,102 +34,73 @@ callbacks in any hot-path executable.
 from __future__ import annotations
 
 import contextlib
-import dataclasses
-from typing import Any, Callable, Iterator
+from typing import Any, Iterator
 
 import numpy as np
 
 __all__ = [
-    "CompileWatch", "SyncWatch", "sync_scope", "run_service_gate",
+    "CompileWatch", "SyncWatch", "host_callbacks", "sync_scope",
+    "run_service_gate",
 ]
 
 
 # ------------------------------------------------------------ compile watch
 
 
-@dataclasses.dataclass
-class CompileEvent:
-    """One XLA compilation observed by :class:`CompileWatch`."""
-
-    name: str                   # HLO module name, e.g. "jit__dc_solve_vmapped"
-    hlo: str                    # optimized HLO text ("" if unavailable)
-
-    @property
-    def host_callbacks(self) -> list[str]:
-        if not self.hlo:
-            return []
-        from repro.roofline.hlo_parse import host_callback_ops
-
-        return host_callback_ops(self.hlo)
+# the jax.monitoring duration event recorded around each backend compile
+# (a persistent-cache retrieval records it too)
+BACKEND_COMPILE_EVENT = "/jax/core/compile/backend_compile_duration"
 
 
 class CompileWatch:
     """Context manager counting XLA compilations while active.
 
-    Wraps ``jax._src.compiler.backend_compile`` — every jit lowering
-    (pjit, pmap, eager-op fallback) funnels through it, so ``count``
-    is the ground truth the static recompile rules approximate.
-    Re-entrant use is rejected (the wrap is process-global).
+    Registers a ``jax.monitoring`` duration listener for
+    :data:`BACKEND_COMPILE_EVENT`: every jit lowering that reaches the
+    backend records it once, so ``count`` is the ground truth the
+    static recompile rules approximate and a jit cache hit counts zero.
+    Re-entrant use is rejected (the listener is process-global).
     """
 
     _active: "CompileWatch | None" = None
 
-    def __init__(self, *, capture_hlo: bool = True):
-        self.capture_hlo = capture_hlo
-        self.events: list[CompileEvent] = []
-        self._orig: Callable | None = None
+    def __init__(self) -> None:
+        self.names: list[str] = []
+        self.seconds = 0.0
 
     @property
     def count(self) -> int:
-        return len(self.events)
+        return len(self.names)
 
-    @property
-    def names(self) -> list[str]:
-        return [e.name for e in self.events]
-
-    def host_callback_findings(self) -> list[tuple[str, str]]:
-        """(module name, op line) for every host callback in any
-        compiled executable observed by this watch."""
-        return [
-            (e.name, op) for e in self.events for op in e.host_callbacks
-        ]
+    def _listen(self, event: str, duration: float, **kwargs) -> None:
+        if event == BACKEND_COMPILE_EVENT:
+            self.names.append(str(kwargs.get("fun_name", "<unknown>")))
+            self.seconds += duration
 
     def __enter__(self) -> "CompileWatch":
+        import jax.monitoring
+
         if CompileWatch._active is not None:
             raise RuntimeError("CompileWatch is not re-entrant")
-        from jax._src import compiler as _compiler
-
-        self._orig = _compiler.backend_compile
-        orig = self._orig
-
-        def wrapped(backend, module, options, host_callbacks):
-            exe = orig(backend, module, options, host_callbacks)
-            name = "<unknown>"
-            try:
-                name = str(module.operation.attributes["sym_name"]).strip('"')
-            # best-effort metadata: a failed name extraction must not
-            # fail the compile it is observing
-            except Exception:  # repro: ignore[swallowed-error]
-                pass
-            hlo = ""
-            if self.capture_hlo:
-                try:
-                    hlo = exe.hlo_modules()[0].to_string()
-                # best-effort evidence capture, same contract as above
-                except Exception:  # repro: ignore[swallowed-error]
-                    pass
-            self.events.append(CompileEvent(name=name, hlo=hlo))
-            return exe
-
-        _compiler.backend_compile = wrapped
+        jax.monitoring.register_event_duration_secs_listener(self._listen)
         CompileWatch._active = self
         return self
 
     def __exit__(self, *exc) -> None:
-        from jax._src import compiler as _compiler
+        import jax.monitoring
 
-        _compiler.backend_compile = self._orig
+        jax.monitoring.unregister_event_duration_listener(self._listen)
         CompileWatch._active = None
+
+
+def host_callbacks(jitted, *args, **kwargs) -> list[str]:
+    """Host-callback op lines in the executable ``jitted`` compiles for
+    these arguments (arrays or ``jax.ShapeDtypeStruct``s)."""
+    from repro.roofline.hlo_parse import host_callback_ops
+
+    return host_callback_ops(
+        jitted.lower(*args, **kwargs).compile().as_text()
+    )
 
 
 # --------------------------------------------------------------- sync watch
@@ -259,6 +231,31 @@ def _gate_workload(service, rng: np.random.Generator) -> list[int]:
     return rids
 
 
+def _hot_path_callbacks() -> list[tuple[str, str]]:
+    """(executable, op line) for every host callback in the service's
+    device programs: the DC solve and the batched digital baselines."""
+    import jax
+    import jax.numpy as jnp
+
+    from repro.core import baselines, engine
+
+    def spec(*shape):
+        return jax.ShapeDtypeStruct(shape, jnp.float64)
+
+    programs = (
+        ("dc_solve", engine._dc_solve_vmapped, (spec(2, 128, 128), spec(2, 128)), {}),
+        ("cholesky_solve_batch", baselines.cholesky_solve_batch,
+         (spec(2, 16, 16), spec(2, 16)), {}),
+        ("cg_solve_batch", baselines.cg_solve_batch,
+         (spec(2, 16, 16), spec(2, 16)), {"tol": 1e-10, "max_iter": 100}),
+    )
+    return [
+        (name, op)
+        for name, fn, args, kwargs in programs
+        for op in host_callbacks(fn, *args, **kwargs)
+    ]
+
+
 def run_service_gate(
     *, n_devices: int | None = None, seed: int = 0, verbose: bool = False,
 ) -> dict[str, Any]:
@@ -273,7 +270,7 @@ def run_service_gate(
     * ``dispatch_syncs == 0`` — the dispatch phase never materializes
       a device value (host/device overlap is real);
     * ``harvest_syncs > 0`` — the counter is alive (falsifiability);
-    * no host callbacks inside any executable compiled during warmup.
+    * no host callbacks inside the service's device programs.
     """
     from repro.serving.solve_service import SolveService
 
@@ -284,17 +281,16 @@ def run_service_gate(
 
     service = build()
 
-    # warmup drain: all compilation happens here, observed for the
-    # host-callback scan
+    # warmup drain: all compilation happens here
     with CompileWatch() as warmup_watch:
         rng = np.random.default_rng(seed)
         _gate_workload(service, rng)
         warm = service.drain()
-    callbacks = warmup_watch.host_callback_findings()
+    callbacks = _hot_path_callbacks()
 
     # measured drain: identical workload through fresh signature/ticket
     # objects — compile-count and sync-attribution must both be silent
-    with CompileWatch(capture_hlo=False) as watch, SyncWatch() as sync:
+    with CompileWatch() as watch, SyncWatch() as sync:
         rng = np.random.default_rng(seed)
         _gate_workload(service, rng)
         out = service.drain()

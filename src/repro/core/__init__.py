@@ -29,7 +29,7 @@ The physics core is batched end to end (:mod:`repro.core.engine`):
   ``(B, nz, nz)`` operators; a slot a system does not populate stamps
   ``w = 0`` (amp dynamics stay as a stable decoupled subsystem).
 * **vmap vs Pallas path selection** — the operating point is one
-  ``jax.vmap(jnp.linalg.solve)`` over the batch; transient settling
+  batched f32 LU refined to fp64 on the device; transient settling
   uses the exact stacked eigendecomposition up to
   :data:`~repro.core.engine.EIG_STATE_LIMIT` states and the batch-aware
   Pallas ``transient_step``/``transient_sweep`` forward-Euler kernels

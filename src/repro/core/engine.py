@@ -29,10 +29,10 @@ factors the physics into
     + C cell couplings + branch degree, amp rows <= 4 stamps).  Nothing
     of size ``(B, nz, nz)`` is materialized unless a caller asks
     (:meth:`EllBatchedStateSpace.to_dense`).
-* a **vmapped operating point** (:func:`dc_solve_batch`) — one
-  ``jax.vmap(jnp.linalg.solve)`` over the batch (x64; ``repro.core``
-  enables it globally), with the same tiny-leakage fallback the single
-  path uses for singular supports.
+* a **batched operating point** (:func:`dc_solve_batch`) — one f32 LU
+  factorization per system refined to fp64 on the device (TPUs have no
+  f64 LU; see :data:`DC_REFINE_TOL`), with the same tiny-leakage host
+  fallback the single path uses for singular supports.
 * a **batched transient path** (:func:`transient_batch`) — exact modal
   solution via stacked eigendecomposition for small ``nz`` (the
   reference), and :func:`euler_settle_batch`, a forward-Euler sweep
@@ -1053,18 +1053,76 @@ def assemble_batch_ell(
 # ---------------------------------------------------------------------------
 
 
-@jax.jit
-def _dc_solve_vmapped(m: jnp.ndarray, c: jnp.ndarray) -> jnp.ndarray:
-    return jax.vmap(jnp.linalg.solve)(m, -c)
+# Mixed-precision DC solve.  TPUs implement LU only in float32, so the
+# operating point factors the row-equilibrated operator once in f32 and
+# recovers fp64 by iterative refinement: the residual ``-c - M z`` is
+# formed in f64, the correction solved with the f32 factors, until every
+# system's normwise backward error is below DC_REFINE_TOL.  Each pass
+# contracts the error by ~kappa * eps_f32 (the service mix's equilibrated
+# operators have kappa <= 1e4, i.e. <= 1e-3 per pass), so a handful of
+# passes reach f64 accuracy; rows that do not converge within
+# DC_REFINE_MAX_ITERS (singular or f32-unfactorable operators) come back
+# NaN and are repaired on the host by dc_solve_batch_finalize.
+DC_REFINE_TOL = 1e-15
+DC_REFINE_MAX_ITERS = 10
+
+# host-side repairs made by dc_solve_batch_finalize: systems whose device
+# solve came back non-finite and were re-solved with numpy in f64
+DC_STATS = {"host_resolves": 0}
 
 
-# per-device stream variant: each micro-batch gets freshly transferred
-# (B, nz, nz) operand buffers that nothing reads after the solve, so
-# they are donated — XLA reuses the operand allocation for the result
-# instead of holding both live per in-flight micro-batch.
-_dc_solve_vmapped_donated = jax.jit(
-    lambda m, c: jax.vmap(jnp.linalg.solve)(m, -c), donate_argnums=(0, 1)
-)
+def _dc_solve_refined(m: jnp.ndarray, c: jnp.ndarray) -> jnp.ndarray:
+    f64 = jnp.float64
+    m = m.astype(f64)
+    rhs = -c.astype(f64)
+    # row equilibration: the circuit rows span ~1e-8..1e8 (1/C scaling
+    # against amp rates); unit-max rows keep f32 pivoting meaningful
+    scale = jnp.max(jnp.abs(m), axis=2, keepdims=True)
+    scale = jnp.where(scale > 0, scale, 1.0)
+    m = m / scale
+    rhs = rhs / scale[..., 0]
+    factors = jax.lax.linalg.lu(m.astype(jnp.float32))[:2]     # (lu, piv)
+    m_norm = jnp.max(jnp.sum(jnp.abs(m), axis=2), axis=1)       # (B,)
+    rhs_norm = jnp.max(jnp.abs(rhs), axis=1)
+
+    def correction(r):
+        dz = jax.vmap(jax.scipy.linalg.lu_solve)(
+            factors, r.astype(jnp.float32)
+        )
+        return dz.astype(f64)
+
+    def backward_error(z):
+        r = rhs - jnp.sum(m * z[:, None, :], axis=2)
+        denom = m_norm * jnp.max(jnp.abs(z), axis=1) + rhs_norm
+        err = jnp.max(jnp.abs(r), axis=1) / jnp.where(denom > 0, denom, 1.0)
+        # a non-finite row never satisfies the test (NaN compares False)
+        return r, jnp.where(jnp.isfinite(err), err, jnp.inf)
+
+    def body(state):
+        z, r, _err, it = state
+        z = z + correction(r)
+        r, err = backward_error(z)
+        return z, r, err, it + 1
+
+    def cond(state):
+        _z, _r, err, it = state
+        return (it < DC_REFINE_MAX_ITERS) & jnp.any(err > DC_REFINE_TOL)
+
+    z0 = correction(rhs)
+    r0, err0 = backward_error(z0)
+    z, _r, err, _it = jax.lax.while_loop(cond, body, (z0, r0, err0, 0))
+    # unconverged rows go to the host repair path rather than being
+    # delivered at f32 accuracy
+    return jnp.where((err <= DC_REFINE_TOL * 1e3)[:, None], z, jnp.nan)
+
+
+_dc_solve_vmapped = jax.jit(_dc_solve_refined)
+
+# per-device stream variant: each micro-batch gets a freshly transferred
+# (B, nz) constant vector that nothing reads after the solve, so it is
+# donated — XLA writes the (B, nz) state into its allocation.  (The
+# (B, nz, nz) operator has no output of its shape to alias.)
+_dc_solve_vmapped_donated = jax.jit(_dc_solve_refined, donate_argnums=(1,))
 
 # platforms whose runtime implements input/output buffer aliasing; the
 # CPU client ignores donations (with a warning), so fall back there
@@ -1118,6 +1176,7 @@ def dc_solve_batch_finalize(
     z = np.asarray(z_dev)
     bad = ~np.all(np.isfinite(z), axis=1)
     if np.any(bad):
+        DC_STATS["host_resolves"] += int(np.count_nonzero(bad))
         # JAX device buffers materialize as read-only views; copy
         # before patching the re-solved rows in
         z = np.array(z, dtype=np.float64)
@@ -1133,9 +1192,11 @@ def dc_solve_batch(
 ) -> np.ndarray:
     """Steady states ``z_b = -M_b^{-1} c_b`` for the whole batch.
 
-    Runs the vmapped x64 solve on device; systems whose operator is
-    singular (degenerate supports, see the single-system path) are
-    re-solved with the tiny relative leakage ``1e-12 |M|`` to ground.
+    Runs the f32-LU + f64-refinement solve on device; systems whose
+    operator is singular (degenerate supports, see the single-system
+    path) or does not refine to fp64 come back non-finite and are
+    re-solved on the host with the tiny relative leakage ``1e-12 |M|``
+    to ground (counted in :data:`DC_STATS`).
     See :func:`dc_solve_batch_submit` for the ``mesh`` / ``device``
     placement modes and the async split.
     """
@@ -1412,6 +1473,7 @@ def euler_settle_batch(
     """
     from repro.kernels.ops import (
         SWEEP_STATE_LIMIT,
+        ell_kernel_operands,
         ell_transient_sweep,
         sweep_backend,
         sweep_chunk_schedule,
@@ -1480,17 +1542,13 @@ def euler_settle_batch(
             check_every = 50
 
     if isinstance(bss, EllBatchedStateSpace):
-        size = nz + (-nz) % 128
-        w_dtype = jnp.bfloat16 if sweep_dtype == "bfloat16" else jnp.float32
-        wt = jnp.pad(
-            (bss.weights * dt[:, None, None]).astype(w_dtype),
-            ((0, 0), (0, size - nz), (0, 0)),
+        # hoist the kernel layout out of the chunk loop: dt-folded,
+        # slot-major and lane-padded once per sweep
+        idx, wt, ct = ell_kernel_operands(
+            bss.indices, bss.weights * dt[:, None, None],
+            bss.c * dt[:, None], sweep_dtype,
         )
-        idx = jnp.pad(bss.indices, ((0, 0), (0, size - nz), (0, 0)))
-        ct = jnp.pad(
-            (bss.c * dt[:, None]).astype(jnp.float32),
-            ((0, 0), (0, size - nz)),
-        )
+        size = idx.shape[2]
         if z0_full is not None:
             z = jnp.asarray(np.pad(
                 z0_full, ((0, 0), (0, size - nz))).astype(np.float32))

@@ -267,8 +267,8 @@ def operating_point_batch_submit(
     """Host phase of the batched DC analysis + async device dispatch.
 
     Applies the per-system error model and assembles the batch on the
-    shared stamp pattern (host-side numpy), then dispatches the vmapped
-    x64 solve — on one ``device`` (per-device solve streams, see
+    shared stamp pattern (host-side numpy), then dispatches the batched
+    fp64-refined DC solve — on one ``device`` (per-device solve streams, see
     :func:`repro.core.engine.dc_solve_batch_submit`) or sharded over
     ``mesh`` — and returns without blocking.
     """
@@ -302,8 +302,8 @@ def operating_point_batch(
     The per-system error model is applied exactly as in the single path
     (quantize -> perturb -> wiper per netlist, per-amp offset draws with
     the same per-system RNG stream), then the whole batch is assembled
-    on one shared stamp pattern and solved with the engine's vmapped
-    x64 linear solve.  ``x_ref`` is (B, n) (or None to skip errors).
+    on one shared stamp pattern and solved with the engine's batched
+    fp64-refined DC solve.  ``x_ref`` is (B, n) (or None to skip errors).
     ``mesh`` shards the DC solve's batch axis over a 1-d solver mesh
     (:func:`repro.distributed.sharding.solver_mesh`); ``device`` places
     the whole batch on one device instead (the serving streams).  This

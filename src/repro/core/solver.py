@@ -16,8 +16,8 @@ paper evaluates.
 
 ``solve_batch(A, b)`` is the batched entry point: ``A`` is ``(B, n, n)``
 and ``b`` ``(B, n)``; the netlists are built per system (vectorized
-structure-of-arrays stamping) and then assembled, DC-solved (vmapped
-x64 linear solve) and transient-analyzed as one batch on a shared stamp
+structure-of-arrays stamping) and then assembled, DC-solved (batched
+f32 LU refined to fp64) and transient-analyzed as one batch on a shared stamp
 pattern (see :mod:`repro.core.engine`).  ``solve`` is a thin B=1
 wrapper over the same machinery for the analog methods, so single and
 batched results agree by construction.
@@ -25,6 +25,7 @@ batched results agree by construction.
 
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 from typing import Any, Callable
 
@@ -534,11 +535,18 @@ def solve_batch_submit(
     spec = OPAMPS[opamp] if isinstance(opamp, str) else opamp
     ni = IDEAL if nonideal is None else nonideal
 
+    # device work outside the DC dispatch (the netlist transform, the
+    # finish phase) runs on the stream's device, not the process default
+    def on_device():
+        return (jax.default_device(device) if device is not None
+                else contextlib.nullcontext())
+
     if nets is None:
-        nets = _build_nets(
-            a, b, method, d_policy=d_policy, beta=beta, alpha=alpha,
-            params=params,
-        )
+        with on_device():
+            nets = _build_nets(
+                a, b, method, d_policy=d_policy, beta=beta, alpha=alpha,
+                params=params,
+            )
     elif len(nets) != a.shape[0]:
         raise ValueError(f"got {len(nets)} nets for a batch of {a.shape[0]}")
     if pattern is None:
@@ -577,6 +585,10 @@ def solve_batch_submit(
         )
 
     def finish(result: BatchSolveResult) -> BatchSolveResult:
+        with on_device():
+            return _finish(result)
+
+    def _finish(result: BatchSolveResult) -> BatchSolveResult:
         if compute_settling:
             # x_ref reaches the transient engine only on explicit opt-in
             # (or for the estimator-only spectral path, where it merely

@@ -150,10 +150,9 @@ SOLVER_RULES: dict[str, object] = {
 def solver_mesh(n_devices: Optional[int] = None, devices=None):
     """1-d solver mesh over the system-batch axis.
 
-    Built through the jax-0.4.37 shims (:func:`repro.launch.mesh._make_mesh`),
-    so it works on both API generations and on
-    ``XLA_FLAGS=--xla_force_host_platform_device_count=N`` placeholder
-    devices.  ``n_devices=None`` uses every visible device.
+    Works on ``XLA_FLAGS=--xla_force_host_platform_device_count=N``
+    placeholder devices too.  ``n_devices=None`` uses every visible
+    device.
     """
     from repro.launch.mesh import _make_mesh
 

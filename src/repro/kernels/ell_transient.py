@@ -9,36 +9,43 @@ per row, a fixed-width list of ``(column, weight)`` slots:
     dz[i] = sum_k  w[i, k] * z[idx[i, k]]          (+ c[i])
 
 Unused slots carry ``(idx=0, w=0)`` and are exact no-ops, so the same
-gathered row reduction serves every row type.  Per step the kernel
+gathered row reduction serves every row type.  Per step the sweep
 touches ``nz * K`` weights instead of ``nz^2`` — for the proposed
 design (``nz ~ 8n``, amp rows bounded) that is an ~8x traffic reduction
 even for a dense system matrix and orders of magnitude for sparse ones.
 
-Two variants, mirroring :mod:`repro.kernels.transient_step`:
+Kernel layout (slot-major)
+--------------------------
+The kernels take the slot arrays transposed to ``(B, K, nz)``: the row
+axis ``nz`` (padded to 128) runs along the TPU lanes and the slot axis
+``K`` along sublanes, so a block ``(1, K, bn)`` satisfies Mosaic's
+(8, 128) tiling rule for any ``K`` and the slot reduction is a plain
+sublane sum.  States and constants are ``(B, 1, nz)``: the unit
+sublane axis makes a one-system block legal without padding the batch.
 
-* :func:`ell_sweep_pallas` — ``n_steps`` fused forward-Euler steps with
-  the whole per-system ELL operator VMEM-resident (grid over the batch
-  only) and the same fused ``max |M z + c|`` settling-check reduction as
-  the dense sweep, evaluated at the final state.
-* :func:`ell_step_pallas` — one row-tiled step for operators whose ELL
-  arrays exceed VMEM: the state vector (``nz`` floats — tiny) stays
-  whole per program so the gather never crosses tiles, while ``idx``/
-  ``w`` stream through VMEM in row blocks.
+The gather ``z[idx]`` is done by XLA between kernel launches: Mosaic
+only gathers within one vreg (8 x 128), and the state vector spans
+``nz / 128`` of them.  One step is therefore an XLA gather producing
+the ``(B, K, nz)`` slot values followed by :func:`ell_step_pallas`, the
+Pallas kernel that multiplies them by the slot weights, reduces over
+slots, applies the Euler update and emits ``M z + c``.
+:func:`ell_sweep_pallas` runs ``n_steps`` such steps inside one jitted
+``fori_loop`` (one host dispatch per chunk) and evaluates the settling
+residual ``max |M z' + c|`` at the final state.  Only one row block of
+the slot arrays is VMEM-resident at a time, so every ``nz`` takes the
+same path.
 
-Both use a VPU row reduction over the slot axis (the op is a gather
-plus an FMA per slot — there is no MXU shape here) and read the slot
-arrays row-major.  Callers go through the auto-padding wrappers in
+Both take a ``sweep_dtype`` knob (``"float32"`` default, or
+``"bfloat16"``): the slot weights (and the gathered slot values) are
+stored and multiplied at that precision while the slot-axis
+*accumulation*, the state vector and the settling residual stay float32
+(bf16 storage / fp32 accumulate — the mixed-precision contract the
+refinement layer in :mod:`repro.core.refine` assumes).  bf16 halves the
+per-step weight traffic — the dominant bytes of the sweep — at ~3
+decimal digits of weight precision, which the 1 %-band settling check
+tolerates; anything tighter than the band must come from refinement,
+not the sweep.  Callers go through the wrapper in
 :mod:`repro.kernels.ops`; the raw kernels assert pre-padded shapes.
-
-Both kernels take a ``sweep_dtype`` knob (``"float32"`` default, or
-``"bfloat16"``): the slot weights are stored and multiplied at that
-precision while the slot-axis *accumulation*, the state vector and the
-settling residual stay float32 (bf16 storage / fp32 accumulate — the
-mixed-precision contract the refinement layer in
-:mod:`repro.core.refine` assumes).  bf16 halves the per-step weight
-traffic — the dominant bytes of the sweep — at ~3 decimal digits of
-weight precision, which the 1 %-band settling check tolerates; anything
-tighter than the band must come from refinement, not the sweep.
 """
 
 from __future__ import annotations
@@ -47,152 +54,111 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 
-
-DEFAULT_ROW_BLOCK = 128
 
 # sweep_dtype values accepted by the sweep kernels and their wrappers
 SWEEP_DTYPES = ("float32", "bfloat16")
 
+# bytes of the double-buffered weight + slot-value blocks one grid step
+# may hold in VMEM (well inside the 16 MiB default scoped limit)
+ELL_BLOCK_BYTES = 8 * 1024 * 1024
+_MAX_LANE_BLOCK = 8192
 
-def _ell_residual(z_row, idx, w, c):
-    """Gathered row reduction: ``(M z + c)`` for one system.
+# constant block index for index maps: a Python 0 traces as int64 under
+# the package's global x64 mode, which Mosaic refuses to lower
+_I0 = np.int32(0)
 
-    z_row: (nz,) f32; idx: (nz, K) int32; w: (nz, K) f32 or bf16;
-    c: (1, nz) f32.  The multiply runs at ``w.dtype``; the slot-axis
-    accumulation is always float32.
+
+def lane_block(nz: int, k: int) -> int:
+    """Row block (lanes) for the ELL step: the largest power-of-two
+    multiple of 128 dividing ``nz`` whose blocks fit
+    :data:`ELL_BLOCK_BYTES` (never below 128)."""
+    assert nz % 128 == 0, nz
+    k_pad = k + (-k) % 8
+    bn = 128
+    while (bn * 2 <= _MAX_LANE_BLOCK and nz % (bn * 2) == 0
+           and 2 * 2 * k_pad * (bn * 2) * 4 <= ELL_BLOCK_BYTES):
+        bn *= 2
+    return bn
+
+
+def _ell_step_kernel(w_ref, g_ref, z_ref, c_ref, out_ref, dz_ref, *, dt: float):
+    w = w_ref[0]                                       # (K, bn) sweep dtype
+    g = g_ref[0].astype(w.dtype)                       # (K, bn) z[idx]
+    dz = jnp.sum((w * g).astype(jnp.float32), axis=0, keepdims=True) \
+        + c_ref[0].astype(jnp.float32)                 # (1, bn)
+    out_ref[0] = (z_ref[0].astype(jnp.float32) + dt * dz).astype(out_ref.dtype)
+    dz_ref[0] = dz
+
+
+@functools.partial(jax.jit, static_argnames=("dt", "interpret"))
+def ell_step_pallas(
+    w: jnp.ndarray,
+    g: jnp.ndarray,
+    z: jnp.ndarray,
+    c: jnp.ndarray,
+    dt: float = 1.0,
+    *,
+    interpret: bool = False,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """One ELL Euler step from pre-gathered slot values.
+
+    ``w``/``g`` are ``(B, K, nz)`` slot weights and slot values
+    ``g[b, k, i] = z[b, idx[b, k, i]]``; ``z``/``c`` are ``(B, 1, nz)``.
+    Returns ``(z + dt * dz, dz)`` with ``dz = M z + c`` at the input
+    state.
     """
-    gathered = jnp.take(z_row, idx, axis=0).astype(w.dtype)   # (nz, K)
-    prod = (w * gathered).astype(jnp.float32)
-    return jnp.sum(prod, axis=1)[None, :] + c                 # (1, nz)
+    bsz, k, nz = w.shape
+    assert g.shape == w.shape and z.shape == (bsz, 1, nz) and c.shape == z.shape, (
+        w.shape, g.shape, z.shape, c.shape)
+    bn = lane_block(nz, k)
+    state = pl.BlockSpec((1, 1, bn), lambda b, i: (b, _I0, i))
+    slots = pl.BlockSpec((1, k, bn), lambda b, i: (b, _I0, i))
+    return pl.pallas_call(
+        functools.partial(_ell_step_kernel, dt=float(dt)),
+        grid=(bsz, nz // bn),
+        in_specs=[slots, slots, state, state],
+        out_specs=[state, state],
+        out_shape=[
+            jax.ShapeDtypeStruct(z.shape, z.dtype),
+            jax.ShapeDtypeStruct(z.shape, jnp.float32),
+        ],
+        name="ell_step",
+        interpret=interpret,
+    )(w, g, z, c)
 
 
-def _ell_sweep_kernel(idx_ref, w_ref, z_ref, c_ref, out_ref, res_ref,
-                      *, n_steps: int, dt: float, sweep_dtype: str):
-    idx = idx_ref[0]                                   # (nz, K)
-    w = w_ref[0].astype(jnp.dtype(sweep_dtype))        # (nz, K)
-    c = c_ref[...].astype(jnp.float32)                 # (1, nz)
-
-    def body(_, zz):
-        return zz + dt * _ell_residual(zz[0], idx, w, c)
-
-    z = jax.lax.fori_loop(0, n_steps, body, z_ref[...].astype(jnp.float32))
-    dz = _ell_residual(z[0], idx, w, c)
-    out_ref[...] = z.astype(out_ref.dtype)
-    res_ref[...] = jnp.max(jnp.abs(dz)).reshape(1, 1)
+def _gather_slots(z: jnp.ndarray, idx: jnp.ndarray, dtype) -> jnp.ndarray:
+    """``(B, 1, nz)`` state, ``(B, K, nz)`` indices -> slot values."""
+    return jax.vmap(lambda zz, ii: zz[0][ii])(z.astype(dtype), idx)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("n_steps", "dt", "interpret", "sweep_dtype")
-)
+@functools.partial(jax.jit, static_argnames=("dt", "interpret"))
 def ell_sweep_pallas(
     idx: jnp.ndarray,
     w: jnp.ndarray,
     z: jnp.ndarray,
     c: jnp.ndarray,
+    n_steps,
     *,
-    n_steps: int,
     dt: float = 1.0,
     interpret: bool = False,
-    sweep_dtype: str = "float32",
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """``n_steps`` fused Euler steps per system, ELL operator VMEM-resident.
+    """``n_steps`` ELL Euler steps per system in one dispatch.
 
-    ``idx``/``w`` are ``(B, nz, K)`` ELL slot arrays, ``z``/``c``
-    ``(B, nz)``.  Returns ``(z', res)`` with
-    ``res[b, 0] = max_i |M_b z'_b + c_b|_i`` — the fused settling-check
-    reduction evaluated at the final state (matching the dense sweep's
-    contract).  ``sweep_dtype="bfloat16"`` selects the bf16-weight /
-    fp32-accumulate variant (state and residual stay f32); pass ``w``
-    already cast to bf16 to also halve the weight traffic.
+    ``idx``/``w`` are ``(B, K, nz)`` slot-major ELL arrays (``w`` at
+    the sweep dtype), ``z``/``c`` ``(B, 1, nz)``.  ``n_steps`` is a
+    traced count, so every chunk length shares one executable.  Returns
+    ``(z', res)`` with ``res[b] = max_i |M_b z'_b + c_b|_i`` — the
+    settling-check reduction at the final state.
     """
-    bsz, nz, k = idx.shape
-    assert w.shape == idx.shape and z.shape == (bsz, nz) and c.shape == z.shape, (
-        idx.shape, w.shape, z.shape, c.shape)
-    assert nz % 128 == 0, idx.shape
-    assert sweep_dtype in SWEEP_DTYPES, sweep_dtype
+    def body(_, zz):
+        return ell_step_pallas(w, _gather_slots(zz, idx, w.dtype), zz, c, dt,
+                               interpret=interpret)[0]
 
-    return pl.pallas_call(
-        functools.partial(_ell_sweep_kernel, n_steps=int(n_steps), dt=float(dt),
-                          sweep_dtype=sweep_dtype),
-        grid=(bsz,),
-        in_specs=[
-            pl.BlockSpec((1, nz, k), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, nz, k), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, nz), lambda b: (b, 0)),
-            pl.BlockSpec((1, nz), lambda b: (b, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, nz), lambda b: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b: (b, 0)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bsz, nz), z.dtype),
-            jax.ShapeDtypeStruct((bsz, 1), jnp.float32),
-        ],
-        interpret=interpret,
-    )(idx, w, z, c)
-
-
-def _ell_step_kernel(idx_ref, w_ref, zfull_ref, zi_ref, c_ref,
-                     out_ref, res_ref, *, dt: float, sweep_dtype: str):
-    idx = idx_ref[0]                                   # (bm, K)
-    w = w_ref[0].astype(jnp.dtype(sweep_dtype))        # (bm, K)
-    z = zfull_ref[0].astype(jnp.float32)               # (nz,) whole state
-    gathered = jnp.take(z, idx, axis=0).astype(w.dtype)  # (bm, K)
-    dz = jnp.sum((w * gathered).astype(jnp.float32), axis=1)[None, :] \
-        + c_ref[...].astype(jnp.float32)
-    out_ref[...] = (zi_ref[...].astype(jnp.float32) + dt * dz).astype(out_ref.dtype)
-    res_ref[...] = jnp.max(jnp.abs(dz)).reshape(1, 1)
-
-
-@functools.partial(
-    jax.jit, static_argnames=("dt", "block", "interpret", "sweep_dtype")
-)
-def ell_step_pallas(
-    idx: jnp.ndarray,
-    w: jnp.ndarray,
-    z: jnp.ndarray,
-    c: jnp.ndarray,
-    dt: float = 1.0,
-    *,
-    block: int = DEFAULT_ROW_BLOCK,
-    interpret: bool = False,
-    sweep_dtype: str = "float32",
-) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """One row-tiled ELL Euler step: idx/w (B, nz, K), z/c (B, nz).
-
-    Returns ``(z', res)`` where ``res[b, i_block]`` holds the block-max
-    of ``|M_b z_b + c_b|`` — reduce over axis 1 for the per-system
-    settling check.  Used when the whole ELL operator does not fit
-    VMEM; the state vector still does, so the gather stays local.
-    ``sweep_dtype`` as in :func:`ell_sweep_pallas`.
-    """
-    bsz, nz, k = idx.shape
-    assert w.shape == idx.shape and z.shape == (bsz, nz) and c.shape == z.shape, (
-        idx.shape, w.shape, z.shape, c.shape)
-    assert nz % block == 0, (idx.shape, block)
-    assert sweep_dtype in SWEEP_DTYPES, sweep_dtype
-
-    return pl.pallas_call(
-        functools.partial(_ell_step_kernel, dt=float(dt),
-                          sweep_dtype=sweep_dtype),
-        grid=(bsz, nz // block),
-        in_specs=[
-            pl.BlockSpec((1, block, k), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, block, k), lambda b, i: (b, i, 0)),
-            pl.BlockSpec((1, nz), lambda b, i: (b, 0)),     # whole state
-            pl.BlockSpec((1, block), lambda b, i: (b, i)),  # state tile
-            pl.BlockSpec((1, block), lambda b, i: (b, i)),  # C tile
-        ],
-        out_specs=[
-            pl.BlockSpec((1, block), lambda b, i: (b, i)),
-            pl.BlockSpec((1, 1), lambda b, i: (b, i)),
-        ],
-        out_shape=[
-            jax.ShapeDtypeStruct((bsz, nz), z.dtype),
-            jax.ShapeDtypeStruct((bsz, nz // block), jnp.float32),
-        ],
-        interpret=interpret,
-    )(idx, w, z, z, c)
+    z = jax.lax.fori_loop(0, n_steps, body, z)
+    _, dz = ell_step_pallas(w, _gather_slots(z, idx, w.dtype), z, c, 0.0,
+                            interpret=interpret)
+    return z, jnp.max(jnp.abs(dz), axis=(1, 2))

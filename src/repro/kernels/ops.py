@@ -4,7 +4,9 @@ Responsibilities:
 * pad inputs to block multiples (zero padding is exact for all three
   kernels: matmul/reduction zeros are neutral, and the assembly kernel's
   padded diagonal region is sliced away);
-* choose interpret mode automatically off-TPU (CPU validation path);
+* choose interpret mode automatically off-TPU (the CPU test path), and
+  count every launch by mode in :data:`KERNEL_STATS`, so a run on the
+  chip can prove that no kernel ran interpreted;
 * present clean shapes (vectors in, vectors out).
 """
 
@@ -23,8 +25,16 @@ from repro.kernels import spd_transform as _tr
 from repro.kernels import transient_step as _st
 
 
-def _interpret_default() -> bool:
-    return jax.default_backend() != "tpu"
+# kernel launches through these wrappers, by execution mode
+KERNEL_STATS = {"compiled": 0, "interpreted": 0}
+
+
+def _resolve_interpret(interpret: bool | None) -> bool:
+    """Interpret mode unless on TPU (or as the caller forces); counted."""
+    if interpret is None:
+        interpret = jax.default_backend() != "tpu"
+    KERNEL_STATS["interpreted" if interpret else "compiled"] += 1
+    return interpret
 
 
 def _pad_to(x: jnp.ndarray, mults: tuple[int, ...]) -> jnp.ndarray:
@@ -45,7 +55,7 @@ def crosspoint_mvm(
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """Crossbar currents I = G @ V.  v may be (k,) or (k, batch)."""
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = _resolve_interpret(interpret)
     squeeze = v.ndim == 1
     if squeeze:
         v = v[:, None]
@@ -68,7 +78,7 @@ def transient_step(
     interpret: bool | None = None,
 ) -> jnp.ndarray:
     """One fused Euler step z + dt (M z + c); z may be (n,) or (n, b)."""
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = _resolve_interpret(interpret)
     squeeze = z.ndim == 1
     if squeeze:
         z = z[:, None]
@@ -100,21 +110,22 @@ def transient_step_batched(
     Returns ``(z', res)`` with ``res`` the per-system fused
     settling-check reduction ``max_i |M z + c|_i``.
     """
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = _resolve_interpret(interpret)
     bsz, n, _ = m.shape
     bm, bk = block
     mult = math.lcm(bm, bk)
     size = n + (-n) % mult
     mp = _pad_to(m, (1, size, size))
-    zp = _pad_to(z, (1, size))
-    cp = _pad_to(c, (1, size))
-    out, res = _st.transient_step_batched_pallas(
+    zp = _pad_to(z, (1, size))[:, None, :]
+    cp = _pad_to(c, (1, size))[:, None, :]
+    out, dz = _st.transient_step_batched_pallas(
         mp, zp, cp, dt, block=block, interpret=interpret
     )
-    return out[:, :n], jnp.max(res, axis=1)
+    return out[:, 0, :n], jnp.max(jnp.abs(dz), axis=(1, 2))
 
 
-# fused-sweep VMEM budget: (n^2 + 3n) f32 per system must fit on-chip
+# fused-sweep VMEM budget: the double-buffered (n, n) f32 operator of one
+# system must fit on-chip (see transient_step.sweep_vmem_bytes)
 SWEEP_STATE_LIMIT = 1792
 
 # ---------------------------------------------------------------------------
@@ -125,18 +136,9 @@ SWEEP_STATE_LIMIT = 1792
 # reads nz*K (weight, index) pairs — 2x the bytes per slot.  ELL
 # therefore wins on traffic whenever the ELL width K is below
 # ELL_FILL_CUTOFF * nz, and it additionally removes the O(B nz^2) host
-# assembly and transfer.  The fused ELL sweep needs the whole slot
-# array on-chip: ~ nz*K*8 + 3*nz*4 bytes per system must fit the VMEM
-# budget, else the row-tiled per-step kernel takes over (state vector
-# whole, slots streamed).
+# assembly and transfer.  The ELL step streams its slot arrays through
+# VMEM one row block at a time, so it has no size limit.
 ELL_FILL_CUTOFF = 0.5
-ELL_VMEM_BUDGET = 12 * 1024 * 1024
-
-
-def ell_sweep_fits_vmem(nz: int, k: int) -> bool:
-    """Whether one system's padded ELL operator is VMEM-resident."""
-    nz_p = nz + (-nz) % 128
-    return (nz_p * k * 8 + 3 * nz_p * 4) <= ELL_VMEM_BUDGET
 
 
 def sweep_chunk_schedule(
@@ -170,13 +172,29 @@ def sweep_backend(nz: int, k: int | None) -> str:
     """Pick the transient-sweep backend for an operator family.
 
     ``k`` is the ELL slot width (None for a dense-only caller).
-    Returns ``"ell"`` (fused ELL sweep), ``"ell-step"`` (row-tiled ELL,
-    operator exceeds VMEM), ``"dense"`` (fused dense sweep) or
-    ``"dense-step"`` (tiled dense per-step kernel).
+    Returns ``"ell"`` (ELL-SpMV sweep), ``"dense"`` (fused dense sweep,
+    operator VMEM-resident) or ``"dense-step"`` (tiled dense per-step
+    kernel).
     """
     if k is not None and k < ELL_FILL_CUTOFF * nz:
-        return "ell" if ell_sweep_fits_vmem(nz, k) else "ell-step"
+        return "ell"
     return "dense" if nz <= SWEEP_STATE_LIMIT else "dense-step"
+
+
+def ell_kernel_operands(
+    idx: jnp.ndarray, w: jnp.ndarray, c: jnp.ndarray,
+    sweep_dtype: str = "float32",
+) -> tuple[jnp.ndarray, jnp.ndarray, jnp.ndarray]:
+    """Row-major ELL ``(B, nz, K)`` slots + ``(B, nz)`` constants ->
+    the kernels' slot-major layout: ``(B, K, nz_p)`` indices and weights
+    (at the sweep dtype) and ``(B, nz_p)`` f32 constants, ``nz_p`` the
+    next multiple of 128 (padded rows are zero-weight no-ops)."""
+    bsz, nz, k = idx.shape
+    size = nz + (-nz) % 128
+    w_dtype = jnp.bfloat16 if sweep_dtype == "bfloat16" else jnp.float32
+    idx_t = _pad_to(idx.transpose(0, 2, 1), (1, 1, size))
+    w_t = _pad_to(w.astype(w_dtype).transpose(0, 2, 1), (1, 1, size))
+    return idx_t, w_t, _pad_to(c.astype(jnp.float32), (1, size))
 
 
 def ell_transient_sweep(
@@ -191,48 +209,35 @@ def ell_transient_sweep(
     padded: bool = False,
     sweep_dtype: str = "float32",
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """``n_steps`` fused ELL Euler steps; idx/w (B, nz, K), z/c (B, nz).
+    """``n_steps`` ELL Euler steps; idx/w (B, nz, K), z/c (B, nz).
 
-    Pads ``nz`` to the row-block multiple (padded rows carry ``w = 0``
-    slots pointing at column 0 — exact no-ops) and dispatches between
-    the VMEM-resident fused sweep and the row-tiled per-step kernel by
-    the :func:`ell_sweep_fits_vmem` budget.  Returns ``(z', res)`` with
-    the per-system residual ``max_i |M z' + c|_i`` at the final state.
+    Converts to the kernels' slot-major, 128-padded layout
+    (:func:`ell_kernel_operands`) and runs the sweep
+    (:func:`repro.kernels.ell_transient.ell_sweep_pallas`).  Returns
+    ``(z', res)`` with the per-system residual ``max_i |M z' + c|_i``
+    at the final state.
 
-    ``padded=True`` asserts the caller already block-padded every
-    operand — the loop-hoisted fast path for settling sweeps that
-    launch many chunks over the same operator batch.
+    ``padded=True`` asserts the caller already passed
+    :func:`ell_kernel_operands` output for ``idx``/``w``/``c`` and a
+    ``(B, nz_p)`` state — the loop-hoisted fast path for settling
+    sweeps that launch many chunks over the same operator batch.
 
     ``sweep_dtype="bfloat16"`` runs the bf16-weight / fp32-accumulate
-    kernel variant: the slot weights are cast to bf16 storage here (so
-    the per-step weight traffic halves) while the state, the slot-axis
-    accumulation and the settling residual stay float32.
+    variant: the slot weights are stored in bf16 (so the per-step
+    weight traffic halves) while the state, the slot-axis accumulation
+    and the settling residual stay float32.
     """
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = _resolve_interpret(interpret)
     assert sweep_dtype in _ell.SWEEP_DTYPES, sweep_dtype
-    bsz, nz, k = idx.shape
+    nz = z.shape[1]
     if not padded:
-        size = nz + (-nz) % 128
-        idx = _pad_to(idx, (1, size, 1))
-        w = _pad_to(w, (1, size, 1))
-        z = _pad_to(z, (1, size))
-        c = _pad_to(c, (1, size))
-    if sweep_dtype == "bfloat16" and w.dtype != jnp.bfloat16:
-        w = w.astype(jnp.bfloat16)
-    if ell_sweep_fits_vmem(nz, k):
-        out, res = _ell.ell_sweep_pallas(
-            idx, w, z, c, n_steps=n_steps, dt=dt, interpret=interpret,
-            sweep_dtype=sweep_dtype,
-        )
-        return out[:, :nz], res[:, 0]
-    for _ in range(n_steps):
-        z, _ = _ell.ell_step_pallas(idx, w, z, c, dt, interpret=interpret,
-                                    sweep_dtype=sweep_dtype)
-    # dt=0 step: state unchanged, residual evaluated at the *final*
-    # state — matching the fused kernel's contract
-    _zf, res = _ell.ell_step_pallas(idx, w, z, c, 0.0, interpret=interpret,
-                                    sweep_dtype=sweep_dtype)
-    return z[:, :nz], jnp.max(res, axis=1)
+        idx, w, c = ell_kernel_operands(idx, w, c, sweep_dtype)
+        z = _pad_to(z, (1, idx.shape[2]))
+    out, res = _ell.ell_sweep_pallas(
+        idx, w, z[:, None, :], c[:, None, :], jnp.int32(n_steps), dt=dt,
+        interpret=interpret,
+    )
+    return out[:, 0, :nz], res
 
 
 def transient_sweep(
@@ -264,16 +269,17 @@ def transient_sweep(
     ELL bf16 kernels (the dense MXU kernels accumulate in f32 either
     way, so rounding the weights is the entire dtype effect).
     """
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = _resolve_interpret(interpret)
     assert sweep_dtype in _ell.SWEEP_DTYPES, sweep_dtype
     if sweep_dtype == "bfloat16" and not m_transposed:
         m = m.astype(jnp.bfloat16).astype(jnp.float32)
     bsz, n, _ = m.shape
     if m_transposed:
-        out, res = _st.transient_sweep_pallas(
-            m, z, c, n_steps=n_steps, dt=dt, interpret=interpret
+        out, dz = _st.transient_sweep_pallas(
+            m, z[:, None, :], c[:, None, :], n_steps=n_steps, dt=dt,
+            interpret=interpret,
         )
-        return out, res[:, 0]
+        return out[:, 0], jnp.max(jnp.abs(dz), axis=(1, 2))
     if n > SWEEP_STATE_LIMIT:
         # pad once so the per-step wrapper's _pad_to is a no-op view
         bm, bk = _st.DEFAULT_BATCHED_BLOCK
@@ -291,11 +297,11 @@ def transient_sweep(
     mp = _pad_to(m, (1, size, size))
     zp = _pad_to(z, (1, size))
     cp = _pad_to(c, (1, size))
-    out, res = _st.transient_sweep_pallas(
-        mp.transpose(0, 2, 1), zp, cp, n_steps=n_steps, dt=dt,
-        interpret=interpret,
+    out, dz = _st.transient_sweep_pallas(
+        mp.transpose(0, 2, 1), zp[:, None, :], cp[:, None, :],
+        n_steps=n_steps, dt=dt, interpret=interpret,
     )
-    return out[:, :n], res[:, 0]
+    return out[:, 0, :n], jnp.max(jnp.abs(dz), axis=(1, 2))
 
 
 def spd_transform_arrays(
@@ -312,7 +318,7 @@ def spd_transform_arrays(
     with ``d_policy="proposed"`` — the Eq. 22 D built from the fused
     column-|A| reduction; Eqs. 15-16 assembled tile by tile.
     """
-    interpret = _interpret_default() if interpret is None else interpret
+    interpret = _resolve_interpret(interpret)
     n = a.shape[0]
     br, bc = block
     ap = _pad_to(a, (br, bc))

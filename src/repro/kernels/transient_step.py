@@ -19,22 +19,22 @@ both views stream through VMEM with no gather.
 Batched variants (one state vector per system, per-system operator):
 
 * :func:`transient_step_batched_pallas` — one step for a batch
-  ``Z'_b = Z_b + dt (M_b Z_b + C_b)`` with a *fused settling-check
-  reduction*: alongside the updated states it emits the per-system
-  ``max_i |M_b z_b + c_b|_i`` partials (the steady-state residual; zero
-  exactly at the operating point), so the driving sweep can test
-  convergence without a second pass over M.
+  ``Z'_b = Z_b + dt (M_b Z_b + C_b)`` that also emits ``dz = M_b z_b +
+  c_b`` (the steady-state residual; zero exactly at the operating
+  point), so the driving sweep can test convergence without a second
+  pass over M.
 * :func:`transient_sweep_pallas` — ``n_steps`` fused steps with the
   whole per-system operator VMEM-resident (grid over the batch only):
   the physics iterates on-chip and M crosses HBM once per *chunk*
-  instead of once per step.  Usable while ``(n^2 + 3n) * 4`` bytes fit
-  in VMEM; the engine falls back to the tiled per-step kernel beyond.
+  instead of once per step.  Usable while the double-buffered
+  ``n^2 * 4``-byte operator fits in VMEM (``sweep_vmem_bytes``); the
+  engine falls back to the tiled per-step kernel beyond.
 
-Both read M row-major; the per-step MVM uses a VPU row reduction (the
-op is memory-bound at ~2 flops/byte, so the reduction — not the MXU —
-is the roofline-appropriate unit).  Callers go through the auto-padding
-wrappers in :mod:`repro.kernels.ops`; the raw kernels assert
-block-multiple shapes.
+The batched kernels run their matvecs on the MXU at ``HIGHEST``
+precision (f32 semantics; the default single bf16 pass would move the
+settle point by ~1e-3) and take states as ``(B, 1, n)``.  Callers go
+through the auto-padding wrappers in :mod:`repro.kernels.ops`; the raw
+kernels assert block-multiple shapes.
 
 Dense <-> ELL crossover
 -----------------------
@@ -52,12 +52,10 @@ crossover model:
   their true degree.  The switch picks ELL whenever
   ``K < ELL_FILL_CUTOFF * nz`` (cutoff 0.5 = the break-even of the
   2-arrays-per-slot format).
-* **VMEM budget** — the fused dense sweep holds ``(nz^2 + 3 nz) * 4``
-  bytes per system on-chip (``SWEEP_STATE_LIMIT``); the fused ELL
-  sweep holds ``nz * K * 8 + 3 nz * 4`` (``ELL_VMEM_BUDGET``).  Each
-  side degrades to its per-step tiled kernel beyond its budget — but
-  the ELL budget is crossed ~``nz / 2K`` times later, which is what
-  lets the settling sweeps reach ``nz`` in the tens of thousands.
+* **VMEM budget** — the fused dense sweep holds the double-buffered
+  ``nz^2 * 4``-byte operator per system on-chip (``SWEEP_STATE_LIMIT``)
+  and degrades to its per-step tiled kernel beyond; the ELL step
+  streams one row block of slots at a time and has no size limit.
 * **gather cost** — the ELL row reduction pays one gather per slot; on
   sparse systems the traffic win dominates, at fill ratios near the
   cutoff the dense MXU/VPU stream wins, which is why the switch is by
@@ -70,6 +68,7 @@ import functools
 
 import jax
 import jax.numpy as jnp
+import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
@@ -130,14 +129,31 @@ def transient_step_pallas(
 
 
 # ---------------------------------------------------------------------------
-# Batched step (per-system operators) with fused settling-check reduction
+# Batched step (per-system operators) with fused settling-check output
 # ---------------------------------------------------------------------------
+#
+# Batched layouts: per-system states and constants are (B, 1, n) — the
+# unit sublane axis makes a one-system block ``(1, 1, bn)`` legal under
+# Mosaic's (8, 128) tiling rule without padding the batch (a dense
+# operator block per system is already as large as VMEM allows).
 
 DEFAULT_BATCHED_BLOCK = (128, 128)
 
+# constant block index for index maps: a Python 0 traces as int64 under
+# the package's global x64 mode, which Mosaic refuses to lower
+_I0 = np.int32(0)
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _row_matvec(z, m_t):
+    """``z @ m_t`` for a row vector: ``(1, k) x (k, bm) -> (1, bm)``, f32."""
+    return jnp.dot(z, m_t, precision=_HIGHEST,
+                   preferred_element_type=jnp.float32)
+
 
 def _step_batched_kernel(
-    m_ref, zk_ref, zi_ref, c_ref, out_ref, res_ref, acc_ref,
+    m_ref, zk_ref, zi_ref, c_ref, out_ref, dz_ref, acc_ref,
     *, n_k_blocks: int, dt: float
 ):
     k = pl.program_id(2)
@@ -146,17 +162,20 @@ def _step_batched_kernel(
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    # row reduction: acc[0, i] += sum_k M[b, i, k] z[b, k]
+    # acc[0, i] += sum_k M[b, i, k] z[b, k]  (M tile read transposed)
     m = m_ref[0].astype(jnp.float32)                  # (bm, bk)
-    zk = zk_ref[...].astype(jnp.float32)              # (1, bk)
-    acc_ref[...] += jnp.sum(m * zk, axis=1)[None, :]
+    zk = zk_ref[0].astype(jnp.float32)                # (1, bk)
+    acc_ref[...] += jax.lax.dot_general(
+        zk, m, (((1,), (1,)), ((), ())), precision=_HIGHEST,
+        preferred_element_type=jnp.float32,
+    )
 
     @pl.when(k == n_k_blocks - 1)
     def _update():
-        dz = acc_ref[...] + c_ref[...].astype(jnp.float32)
-        z = zi_ref[...].astype(jnp.float32)
-        out_ref[...] = (z + dt * dz).astype(out_ref.dtype)
-        res_ref[...] = jnp.max(jnp.abs(dz)).reshape(1, 1)
+        dz = acc_ref[...] + c_ref[0].astype(jnp.float32)
+        z = zi_ref[0].astype(jnp.float32)
+        out_ref[0] = (z + dt * dz).astype(out_ref.dtype)
+        dz_ref[0] = dz
 
 
 @functools.partial(jax.jit, static_argnames=("dt", "block", "interpret"))
@@ -169,18 +188,18 @@ def transient_step_batched_pallas(
     block: tuple[int, int] = DEFAULT_BATCHED_BLOCK,
     interpret: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """One fused Euler step per system: m (B, n, n), z/c (B, n).
+    """One fused Euler step per system: m (B, n, n), z/c (B, 1, n).
 
-    Returns ``(z', res)`` where ``res[b, i_block]`` holds the block-max
-    of ``|M_b z_b + c_b|`` — reduce over axis 1 for the per-system
-    settling check.
+    Returns ``(z + dt * dz, dz)`` with ``dz = M z + c`` at the input
+    state — reduce ``max |dz|`` for the per-system settling check.
     """
     bsz, n, n2 = m.shape
-    assert n == n2 and z.shape == (bsz, n) and c.shape == z.shape, (
+    assert n == n2 and z.shape == (bsz, 1, n) and c.shape == z.shape, (
         m.shape, z.shape, c.shape)
     bm, bk = block
     assert n % bm == 0 and n % bk == 0, (m.shape, block)
     n_k_blocks = n // bk
+    row = pl.BlockSpec((1, 1, bm), lambda b, i, kk: (b, _I0, i))
 
     return pl.pallas_call(
         functools.partial(
@@ -189,19 +208,17 @@ def transient_step_batched_pallas(
         grid=(bsz, n // bm, n_k_blocks),
         in_specs=[
             pl.BlockSpec((1, bm, bk), lambda b, i, kk: (b, i, kk)),   # M tile
-            pl.BlockSpec((1, bk), lambda b, i, kk: (b, kk)),          # Z (matmul)
-            pl.BlockSpec((1, bm), lambda b, i, kk: (b, i)),           # Z (update)
-            pl.BlockSpec((1, bm), lambda b, i, kk: (b, i)),           # C tile
+            pl.BlockSpec((1, 1, bk), lambda b, i, kk: (b, _I0, kk)),  # Z (matmul)
+            row,                                                      # Z (update)
+            row,                                                      # C tile
         ],
-        out_specs=[
-            pl.BlockSpec((1, bm), lambda b, i, kk: (b, i)),
-            pl.BlockSpec((1, 1), lambda b, i, kk: (b, i)),
-        ],
+        out_specs=[row, row],
         out_shape=[
-            jax.ShapeDtypeStruct((bsz, n), z.dtype),
-            jax.ShapeDtypeStruct((bsz, n // bm), jnp.float32),
+            jax.ShapeDtypeStruct(z.shape, z.dtype),
+            jax.ShapeDtypeStruct(z.shape, jnp.float32),
         ],
         scratch_shapes=[pltpu.VMEM((1, bm), jnp.float32)],
+        name="dense_step",
         interpret=interpret,
     )(m, z, z, c)
 
@@ -211,20 +228,38 @@ def transient_step_batched_pallas(
 # ---------------------------------------------------------------------------
 
 
-def _sweep_kernel(mt_ref, z_ref, c_ref, out_ref, res_ref, *, n_steps: int, dt: float):
-    mt = mt_ref[0].astype(jnp.float32)                # (n, n), transposed M
-    c = c_ref[...].astype(jnp.float32)                # (1, n)
+# operator rows per MXU pass of the fused sweep's matvec: bounds the
+# in-kernel temporaries (the f32 HIGHEST-precision split of one chunk)
+# instead of materializing the whole (n, n) operator as a value
+_SWEEP_ROW_CHUNK = 128
 
-    def body(_, zz):
-        dz = jnp.dot(zz, mt, preferred_element_type=jnp.float32) + c
-        return zz + dt * dz
+
+def _sweep_kernel(mt_ref, z_ref, c_ref, out_ref, dz_ref, *, n_steps: int, dt: float):
+    n = mt_ref.shape[1]
+    c = c_ref[0].astype(jnp.float32)                  # (1, n)
+
+    def residual(zz):                                 # M z + c, (1, n)
+        acc = c
+        for k0 in range(0, n, _SWEEP_ROW_CHUNK):
+            rows = slice(k0, k0 + _SWEEP_ROW_CHUNK)
+            acc = acc + _row_matvec(
+                zz[:, rows], mt_ref[0, rows, :].astype(jnp.float32)
+            )
+        return acc
 
     z = jax.lax.fori_loop(
-        0, n_steps, body, z_ref[...].astype(jnp.float32)
+        0, n_steps, lambda _, zz: zz + dt * residual(zz),
+        z_ref[0].astype(jnp.float32),
     )
-    dz = jnp.dot(z, mt, preferred_element_type=jnp.float32) + c
-    out_ref[...] = z.astype(out_ref.dtype)
-    res_ref[...] = jnp.max(jnp.abs(dz)).reshape(1, 1)
+    out_ref[0] = z.astype(out_ref.dtype)
+    dz_ref[0] = residual(z)
+
+
+def sweep_vmem_bytes(n: int) -> int:
+    """Scoped VMEM the fused sweep needs: the double-buffered ``(n, n)``
+    f32 operator block plus headroom for the state blocks and the
+    per-chunk matvec temporaries."""
+    return 2 * n * n * 4 + (16 << 20)
 
 
 @functools.partial(jax.jit, static_argnames=("n_steps", "dt", "interpret"))
@@ -240,30 +275,28 @@ def transient_sweep_pallas(
     """``n_steps`` fused Euler steps per system, operator VMEM-resident.
 
     ``m_t`` is the batch of *transposed* operators (``m_t[b] = M_b.T``)
-    so the in-kernel update is a plain row-vector matmul.  Returns
-    ``(z', res)`` with ``res[b, 0] = max_i |M_b z'_b + c_b|_i`` — the
-    fused settling-check reduction evaluated at the final state.
+    so the in-kernel update is a plain row-vector matmul; ``z``/``c``
+    are ``(B, 1, n)``.  Returns ``(z', dz)`` with ``dz = M z' + c`` at
+    the final state — reduce ``max |dz|`` for the settling check.
     """
     bsz, n, n2 = m_t.shape
-    assert n == n2 and z.shape == (bsz, n) and c.shape == z.shape, (
+    assert n == n2 and z.shape == (bsz, 1, n) and c.shape == z.shape, (
         m_t.shape, z.shape, c.shape)
     assert n % 128 == 0, m_t.shape
+    row = pl.BlockSpec((1, 1, n), lambda b: (b, _I0, _I0))
 
     return pl.pallas_call(
         functools.partial(_sweep_kernel, n_steps=int(n_steps), dt=float(dt)),
         grid=(bsz,),
-        in_specs=[
-            pl.BlockSpec((1, n, n), lambda b: (b, 0, 0)),
-            pl.BlockSpec((1, n), lambda b: (b, 0)),
-            pl.BlockSpec((1, n), lambda b: (b, 0)),
-        ],
-        out_specs=[
-            pl.BlockSpec((1, n), lambda b: (b, 0)),
-            pl.BlockSpec((1, 1), lambda b: (b, 0)),
-        ],
+        in_specs=[pl.BlockSpec((1, n, n), lambda b: (b, _I0, _I0)), row, row],
+        out_specs=[row, row],
         out_shape=[
-            jax.ShapeDtypeStruct((bsz, n), z.dtype),
-            jax.ShapeDtypeStruct((bsz, 1), jnp.float32),
+            jax.ShapeDtypeStruct(z.shape, z.dtype),
+            jax.ShapeDtypeStruct(z.shape, jnp.float32),
         ],
+        compiler_params=pltpu.CompilerParams(
+            vmem_limit_bytes=sweep_vmem_bytes(n)
+        ),
+        name="dense_sweep",
         interpret=interpret,
     )(m_t, z, c)

@@ -37,7 +37,7 @@ from repro.configs import (  # noqa: E402
 )
 from repro.distributed.rules import adjust_batch_rule, make_rules  # noqa: E402
 from repro.distributed.sharding import param_specs, use_rules, logical_spec  # noqa: E402
-from repro.launch.mesh import make_production_mesh, mesh_context  # noqa: E402
+from repro.launch.mesh import make_production_mesh  # noqa: E402
 from repro.models.model import (  # noqa: E402
     cache_logical_axes,
     count_active_params,
@@ -133,7 +133,7 @@ def run_cell(arch: str, shape_name: str, mesh_name: str, *, verbose: bool = True
             rules, cfg, shape.global_batch, multi_pod=multi_pod)
 
     t0 = time.time()
-    with mesh_context(mesh), use_rules(rules):
+    with jax.set_mesh(mesh), use_rules(rules):
         specs = input_specs(cfg, shape)
         if shape.kind == "train":
             optimizer = adamw(3e-4)

@@ -500,6 +500,7 @@ class SolveService:
         self._settle_finish_s = 0.0
         self._unpack_s = 0.0
         self._real_sq = 0.0      # sum n^2 over served systems (stats)
+        self._device_batches = [0] * len(self.devices)
         self._counters: dict[str, Any] = {
             "retries": 0,
             "bisections": 0,
@@ -759,6 +760,7 @@ class SolveService:
         pipe.micro_batches += 1
         pipe.systems += n_real
         pipe.fill_slots += fill
+        self._device_batches[dev] += 1
         return _InFlight(
             pipe=pipe, tickets=tickets, pending=pending, dev=dev,
             injected=fault,
@@ -1191,7 +1193,8 @@ class SolveService:
         ``bisections`` (non-terminal recovery work), ``shed`` /
         ``deadline_expired`` (admission-time rejections),
         ``quarantines`` / ``requeued_on_quarantine`` + the ``breaker``
-        snapshot (stream health), ``fallbacks`` (per-system
+        snapshot (stream health), ``device_micro_batches`` (dispatches
+        per stream, in ``devices`` order), ``fallbacks`` (per-system
         analog→digital re-solves on clean dispatches — the genuine
         numerics signal) vs ``fallbacks_injected`` (re-solves inside
         micro-batches whose dispatch carried injected corruption,
@@ -1239,6 +1242,7 @@ class SolveService:
             "settle_finish_s": self._settle_finish_s,
             "unpack_s": self._unpack_s,
             "devices": len(self.devices),
+            "device_micro_batches": list(self._device_batches),
             "inflight_per_device": self.inflight_per_device,
             "batch_slots": self.batch_slots,
             "retries": c["retries"],

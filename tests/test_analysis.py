@@ -22,6 +22,7 @@ from repro.analysis import (
     Finding,
     SyncWatch,
     diff_baseline,
+    host_callbacks,
     human_report,
     is_suppressed,
     json_report,
@@ -482,8 +483,8 @@ def test_compile_watch_counts_fresh_lowering_only():
     with CompileWatch() as w1:
         f(x).block_until_ready()
     assert w1.count == 1
-    assert w1.host_callback_findings() == []
-    with CompileWatch(capture_hlo=False) as w2:
+    assert host_callbacks(f, x) == []
+    with CompileWatch() as w2:
         f(x).block_until_ready()        # cache hit: no new lowering
     assert w2.count == 0
 
@@ -550,13 +551,13 @@ def test_equal_patterns_share_one_jit_cache_entry():
         return x * pat.n_states
 
     x = np.arange(3.0)
-    with CompileWatch(capture_hlo=False) as warm:
+    with CompileWatch() as warm:
         f(x, p1).block_until_ready()
     assert warm.count == 1
-    with CompileWatch(capture_hlo=False) as again:
+    with CompileWatch() as again:
         f(x, p2).block_until_ready()    # equal pattern: cache hit
     assert again.count == 0
-    with CompileWatch(capture_hlo=False) as differ:
+    with CompileWatch() as differ:
         f(x, p3).block_until_ready()    # different pattern: recompile
     assert differ.count == 1
 

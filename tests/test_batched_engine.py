@@ -89,6 +89,41 @@ def test_operating_point_batch_nonideal_parity():
         )
 
 
+@pytest.mark.parametrize("builder", [build_proposed, build_preliminary])
+def test_dc_solve_refines_f32_lu_to_f64(builder):
+    """The device DC solve (f32 LU + f64 refinement) matches host f64
+    ``np.linalg.solve(M, -c)`` far inside the service's 1e-9 parity,
+    with no system left for the host repair path."""
+    a, x, b = _batch(23, 16, 6, with_sdd=True)
+    bss = engine.assemble_batch([builder(a[k], b[k]) for k in range(6)])
+    resolves = engine.DC_STATS["host_resolves"]
+    z = engine.dc_solve_batch(bss)
+    ref = np.linalg.solve(bss.m, -bss.c[..., None])[..., 0]
+    rel = np.max(np.abs(z - ref), axis=1) / np.max(np.abs(ref), axis=1)
+    assert np.max(rel) <= 1e-12
+    assert engine.DC_STATS["host_resolves"] == resolves
+
+
+def test_dc_solve_counts_host_resolves_of_singular_systems():
+    """A singular operator cannot refine: its row comes back non-finite
+    from the device, is re-solved with the tiny leakage on the host and
+    counted; its batch-mates keep their fp64 device result."""
+    import dataclasses
+
+    a, x, b = _batch(29, 8, 3)
+    bss = engine.assemble_batch([build_proposed(a[k], b[k]) for k in range(3)])
+    m = bss.m.copy()
+    m[1, :, 0] = 0.0                     # state 0 of system 1 floats
+    singular = dataclasses.replace(bss, m=m)
+    resolves = engine.DC_STATS["host_resolves"]
+    z = engine.dc_solve_batch(singular)
+    assert engine.DC_STATS["host_resolves"] == resolves + 1
+    assert np.all(np.isfinite(z))
+    ref = np.linalg.solve(bss.m[[0, 2]], -bss.c[[0, 2], :, None])[..., 0]
+    rel = np.max(np.abs(z[[0, 2]] - ref), axis=1) / np.max(np.abs(ref), axis=1)
+    assert np.max(rel) <= 1e-12
+
+
 def test_pattern_cache_reused_across_batches():
     """Proposed-design patterns depend only on (n, design)."""
     a1, x1, b1 = _batch(17, 8, 3)

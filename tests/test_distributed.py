@@ -97,7 +97,7 @@ _SUBPROCESS_PROG = textwrap.dedent("""
     from repro.configs import get_smoke_config
     from repro.distributed.rules import make_rules, adjust_batch_rule
     from repro.distributed.sharding import use_rules, param_specs
-    from repro.launch.mesh import make_debug_mesh, mesh_context
+    from repro.launch.mesh import make_debug_mesh
     from repro.models.model import init_params, param_logical_axes
     from repro.optim.adamw import adamw
     from repro.training.step import init_train_state, make_train_step
@@ -110,15 +110,14 @@ _SUBPROCESS_PROG = textwrap.dedent("""
     # smoke dims: 4 heads % 4 == 0 -> heads mode on the debug mesh
     opt = adamw(1e-3)
 
-    # jax 0.4.x jit only accepts Sharding objects in in_shardings;
-    # wrap the PartitionSpec trees in NamedSharding (works on both
-    # API generations).  P is a tuple subclass -> needs is_leaf.
+    # in_shardings as NamedSharding trees over the explicit mesh.
+    # P is a tuple subclass -> needs is_leaf.
     from jax.sharding import NamedSharding as NS
     def shard_tree(tree, m):
         return jax.tree.map(lambda s: NS(m, s), tree,
                             is_leaf=lambda x: isinstance(x, P))
 
-    with mesh_context(mesh), use_rules(rules):
+    with jax.set_mesh(mesh), use_rules(rules):
         state = init_train_state(cfg, opt, jax.random.PRNGKey(0))
         p_specs = param_specs(param_logical_axes(cfg), rules)
         specs = {
@@ -159,7 +158,7 @@ _SUBPROCESS_PROG = textwrap.dedent("""
         assert plan.n_devices <= 4
         mesh2 = make_debug_mesh((2, 2), ("data", "model"))
         rules2 = {**make_rules(cfg, model_axis=2), "batch": "data"}
-    with mesh_context(mesh2), use_rules(rules2):
+    with jax.set_mesh(mesh2), use_rules(rules2):
         from jax.sharding import NamedSharding as NS
         rep2 = NS(mesh2, P())
         state2 = {
@@ -191,6 +190,8 @@ _SUBPROCESS_PROG = textwrap.dedent("""
 def test_sharded_train_step_and_elastic_reshard():
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    # forced host devices: the child must never reach for an accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run(
         [sys.executable, "-c", _SUBPROCESS_PROG],
         capture_output=True, text=True, env=env, timeout=600,

@@ -129,13 +129,19 @@ def test_ell_sweep_matches_dense_sweep():
 
 
 def test_ell_sweep_non_block_multiple_n():
-    """Regression: ELL padding is exact for nz far from 128 multiples."""
+    """Regression: ELL padding is exact for nz far from 128 multiples.
+    Every launch is counted by mode (here all interpreted)."""
+    from repro.kernels.ops import KERNEL_STATS
+
     nets, x = _batch(31, 7, 3)                    # nz = 58
     ell = engine.assemble_batch_ell(nets)
     assert ell.n_states % 128 != 0
+    launches = dict(KERNEL_STATS)
     steps, x_final, res, dt = engine.euler_settle_batch(
         ell, x, max_steps=40_000, interpret=True
     )
+    assert KERNEL_STATS["interpreted"] > launches["interpreted"]
+    assert KERNEL_STATS["compiled"] == launches["compiled"]
     assert np.all(steps < 40_000)
     np.testing.assert_allclose(x_final, x, rtol=0.02, atol=1e-3)
     assert np.all(res >= 0.0)

@@ -457,6 +457,8 @@ def test_service_chaos_over_eight_forced_devices():
     parity for every delivered solution."""
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    # forced host devices: the child must never reach for an accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run(
         [sys.executable, "-c", _CHAOS_PROG],
         capture_output=True, text=True, env=env, timeout=600,

@@ -667,6 +667,8 @@ def test_service_streams_over_forced_devices():
     round-robin placement over 4 forced host devices keeps 1e-9 parity."""
     env = dict(os.environ)
     env["PYTHONPATH"] = "src"
+    # forced host devices: the child must never reach for an accelerator
+    env["JAX_PLATFORMS"] = "cpu"
     out = subprocess.run(
         [sys.executable, "-c", _SUBPROCESS_PROG],
         capture_output=True, text=True, env=env, timeout=600,
