@@ -25,10 +25,11 @@ from repro.analysis.report import (
 from repro.analysis.rules import ALL_RULES
 from repro.analysis.runtime import (
     CompileWatch,
+    SpanTotals,
     SyncWatch,
     host_callbacks,
     run_service_gate,
-    sync_scope,
+    span,
 )
 
 __all__ = [
@@ -38,6 +39,7 @@ __all__ = [
     "FileContext",
     "Finding",
     "Rule",
+    "SpanTotals",
     "SyncWatch",
     "diff_baseline",
     "host_callbacks",
@@ -47,6 +49,6 @@ __all__ = [
     "load_baseline",
     "parse_suppressions",
     "run_service_gate",
-    "sync_scope",
+    "span",
     "write_baseline",
 ]

@@ -14,15 +14,26 @@ them falsifiable at run time:
   infeed/outfeed) is flagged even when the compile count itself is
   legitimate warmup.
 * **zero dispatch-phase host syncs** — :class:`SyncWatch` counts host
-  materializations of ``jax.Array`` values, attributed to the phase
-  label the service declares via :func:`sync_scope` (``dispatch`` /
-  ``harvest`` / ``finish`` / ``unpack`` / ``settle_poll``).  On the CPU
+  materializations of ``jax.Array`` values, attributed to the sync
+  label of the innermost :func:`span` that declares one (``dispatch`` /
+  ``harvest`` / ``finish`` / ``unpack`` / ``net_build`` /
+  ``settle_poll``).  On the CPU
   backend ``ArrayImpl`` exposes the buffer protocol, so there is no
   universal interpreter-level hook — instead the watch patches the
   conversion entry points repo code actually calls (``np.asarray`` /
   ``np.array`` / ``jax.device_get`` and the Python-level ``ArrayImpl``
   methods).  The gate asserts ``dispatch == 0`` *and* that harvest-side
   phases counted nonzero syncs — a dead counter cannot pass.
+
+:func:`span` is the one labelling facility of the solve path.  Each
+span opens a ``jax.profiler.TraceAnnotation`` (its events land on the
+profiler's ``/host:CPU`` plane, on the device trace's clock), pushes
+its sync label if it declares one, and adds its count and elapsed
+seconds to the active :class:`SpanTotals` — the one a
+:class:`~repro.serving.solve_service.SolveService` installs for the
+length of its ``drain()``, so spans opened deep in ``core/`` land on
+the service that caused them.  Outside an installed sink a span only
+annotates.
 
 :func:`run_service_gate` is the smoke-drain harness CI runs: warm a
 :class:`~repro.serving.solve_service.SolveService` on a mixed workload,
@@ -34,12 +45,15 @@ callbacks in any hot-path executable.
 from __future__ import annotations
 
 import contextlib
+import contextvars
+import time
 from typing import Any, Iterator
 
 import numpy as np
+from jax.profiler import TraceAnnotation
 
 __all__ = [
-    "CompileWatch", "SyncWatch", "host_callbacks", "sync_scope",
+    "CompileWatch", "SpanTotals", "SyncWatch", "host_callbacks", "span",
     "run_service_gate",
 ]
 
@@ -103,33 +117,82 @@ def host_callbacks(jitted, *args, **kwargs) -> list[str]:
     )
 
 
-# --------------------------------------------------------------- sync watch
+# ------------------------------------------------------------------ spans
 
-# the scope-label stack the instrumented service pushes phases onto;
-# index 0 is the ambient (unattributed) label
+# the sync-label stack SyncWatch charges syncs to; index 0 is the
+# ambient (unattributed) label.  Only spans that declare a label push.
 _SCOPE_STACK: list[str] = ["ambient"]
 
 
-@contextlib.contextmanager
-def sync_scope(label: str) -> Iterator[None]:
-    """Attribute host syncs inside the block to ``label``.
+class SpanTotals:
+    """Count and elapsed seconds per span name, for one owner."""
 
-    Near-zero overhead when no :class:`SyncWatch` is installed (a list
-    push/pop per block), so the service keeps its phases labeled
-    unconditionally.
+    def __init__(self) -> None:
+        self._totals: dict[str, list] = {}     # name -> [count, seconds]
+
+    def add(self, name: str, seconds: float) -> None:
+        entry = self._totals.setdefault(name, [0, 0.0])
+        entry[0] += 1
+        entry[1] += seconds
+
+    def seconds(self, name: str) -> float:
+        return self._totals.get(name, (0, 0.0))[1]
+
+    def snapshot(self) -> dict[str, dict[str, Any]]:
+        """``{name: {"count": int, "s": float}}``, a copy."""
+        return {k: {"count": c, "s": s} for k, (c, s) in self._totals.items()}
+
+    @contextlib.contextmanager
+    def installed(self) -> Iterator["SpanTotals"]:
+        """Make this the sink of every span opened in the block (in
+        this thread or task), nested calls included."""
+        token = _SINK.set(self)
+        try:
+            yield self
+        finally:
+            _SINK.reset(token)
+
+
+_SINK: contextvars.ContextVar[SpanTotals | None] = contextvars.ContextVar(
+    "repro_span_sink", default=None
+)
+
+
+@contextlib.contextmanager
+def span(name: str, *, sync: str | None = None) -> Iterator[None]:
+    """Label the block ``name`` on the profiler trace and time it.
+
+    ``sync`` is the label :class:`SyncWatch` charges the block's host
+    syncs to; a span without one (a timing span) leaves the enclosing
+    label in force, so a sync inside ``core.assemble`` under
+    ``serve.dispatch`` still counts as a ``dispatch`` sync.  The count
+    and seconds go to the installed :class:`SpanTotals`, if any.  A
+    span adds no host sync; with no profiler session its cost is a
+    clock pair, an inactive ``TraceMe`` and, with a sync label, a list
+    push/pop.  Also usable as a decorator.
     """
-    _SCOPE_STACK.append(label)
+    sink = _SINK.get()
+    if sync is not None:
+        _SCOPE_STACK.append(sync)
+    t0 = time.perf_counter()
     try:
-        yield
+        with TraceAnnotation(name):
+            yield
     finally:
-        _SCOPE_STACK.pop()
+        if sink is not None:
+            sink.add(name, time.perf_counter() - t0)
+        if sync is not None:
+            _SCOPE_STACK.pop()
+
+
+# --------------------------------------------------------------- sync watch
 
 
 class SyncWatch:
-    """Context manager counting host materializations per sync scope.
+    """Context manager counting host materializations per sync label.
 
-    ``counts`` maps scope label -> number of ``jax.Array`` host
-    materializations observed inside that scope.  Patched entry points:
+    ``counts`` maps sync label -> number of ``jax.Array`` host
+    materializations observed under that label.  Patched entry points:
     ``numpy.asarray`` / ``numpy.array`` (counted only for jax.Array
     operands), ``jax.device_get``, and the Python-level ``ArrayImpl``
     conversion methods (``tolist`` / ``__float__`` / ``__int__`` /
@@ -141,7 +204,7 @@ class SyncWatch:
 
     def __init__(self) -> None:
         self.counts: dict[str, int] = {}
-        self.calls: list[tuple[str, str]] = []   # (scope, entry point)
+        self.calls: list[tuple[str, str]] = []   # (label, entry point)
         self._saved: list[tuple[Any, str, Any]] = []
         self._in_count = False
 

@@ -62,7 +62,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
-from repro.analysis.runtime import sync_scope
+from repro.analysis.runtime import span
 from repro.core.network import Netlist
 from repro.core.specs import OpAmpSpec, AD712
 
@@ -558,6 +558,7 @@ def _check_batch_params(nets: list[Netlist]):
     return params
 
 
+@span("core.assemble")
 def assemble_batch(
     nets: list[Netlist],
     opamp: OpAmpSpec = AD712,
@@ -1154,14 +1155,17 @@ def dc_solve_batch_submit(
     """
     if device is not None and mesh is not None:
         raise ValueError("pass either device= (stream) or mesh= (shard)")
+    with span("core.transfer"):
+        if device is not None:
+            m = jax.device_put(bss.m, device)
+            c = jax.device_put(bss.c, device)
+        else:
+            m = jnp.asarray(bss.m)
+            c = jnp.asarray(bss.c)
     if device is not None:
-        m = jax.device_put(bss.m, device)
-        c = jax.device_put(bss.c, device)
         if _donation_supported(device):
             return _dc_solve_vmapped_donated(m, c)
         return _dc_solve_vmapped(m, c)
-    m = jnp.asarray(bss.m)
-    c = jnp.asarray(bss.c)
     if mesh is not None:
         from repro.distributed.sharding import shard_system_batch
 
@@ -1383,14 +1387,19 @@ def _settle_loop(step_chunk, z, dt, x_ref, *, rtol, atol, check_every,
     done = np.zeros(b_count, dtype=bool)
     res = np.zeros(b_count, dtype=np.float64)
     taken = 0
-    # the per-chunk convergence poll IS the sweep's sanctioned host
-    # sync — labeled so SyncWatch attributes it to settle_poll, not to
-    # the dispatch phase of whichever service called us
-    with sync_scope("settle_poll"):
-        while taken < max_steps:
-            chunk = min(check_every, max_steps - taken)
+    x_now = None
+    while taken < max_steps:
+        chunk = min(check_every, max_steps - taken)
+        # the host's launch of one chunk: the kernels run async, so this
+        # span times the launches, not the device's work.  Both spans of
+        # the loop carry the settle_poll sync label: the per-chunk
+        # convergence poll IS the sweep's sanctioned host sync, and
+        # SyncWatch must not charge it to the dispatch phase of
+        # whichever service called us
+        with span("core.sweep_chunk", sync="settle_poll"):
             z, r = step_chunk(z, chunk)
-            taken += chunk
+        taken += chunk
+        with span("core.settle_poll", sync="settle_poll"):
             x_now = np.asarray(z[:, :nu], dtype=np.float64)
             # dt was folded into the operator, so the kernel's reduction
             # is dt * max|M z + c|; undo the fold to report the true
@@ -1400,10 +1409,14 @@ def _settle_loop(step_chunk, z, dt, x_ref, *, rtol, atol, check_every,
             newly = ok & ~done
             steps[newly] = taken
             done |= newly
-            if np.all(done):
-                break
-        x_final = np.asarray(z[:, :nu], dtype=np.float64)
-    return steps, x_final, res
+        if np.all(done):
+            break
+    if x_now is None:
+        # no step budget: the state was never polled
+        with span("core.settle_poll", sync="settle_poll"):
+            x_now = np.asarray(z[:, :nu], dtype=np.float64)
+    # the last poll read the final state
+    return steps, x_now, res
 
 
 def euler_settle_batch(
@@ -1588,13 +1601,14 @@ def euler_settle_batch(
     if fused:
         mt = mt.transpose(0, 2, 1)
 
-    if z0_full is not None:
-        z = jnp.asarray(np.pad(
-            z0_full, ((0, 0), (0, size - nz))).astype(np.float32))
-    else:
-        z = jnp.zeros((b_count, size), dtype=jnp.float32)
-    mt_j = jnp.asarray(np.ascontiguousarray(mt))
-    ct_j = jnp.asarray(ct)
+    with span("core.transfer"):
+        if z0_full is not None:
+            z = jnp.asarray(np.pad(
+                z0_full, ((0, 0), (0, size - nz))).astype(np.float32))
+        else:
+            z = jnp.zeros((b_count, size), dtype=jnp.float32)
+        mt_j = jnp.asarray(np.ascontiguousarray(mt))
+        ct_j = jnp.asarray(ct)
 
     def step_chunk(zz, n):
         return transient_sweep(
@@ -1817,45 +1831,47 @@ def transient_batch(
     if method != "euler":
         raise ValueError(f"unknown transient method {method!r}")
 
-    if x_ref is not None:
-        # matrix-free fast path: ELL assembly, settle against the
-        # caller's reference — nothing (B, nz, nz) is ever built
-        bss = assemble_batch_ell(
-            nets, opamp, v_os=v_os, buffers=buffers, pattern=pattern
-        )
-        nu = bss.n_unknowns
-        x_star = np.asarray(x_ref, dtype=np.float64).reshape(len(nets), nu)
-        z_star = None
-    else:
-        bss = assemble_batch(
-            nets, opamp, v_os=v_os, buffers=buffers, pattern=pattern
-        )
-        # settle against the vmapped DC operating point
-        z_star = dc_solve_batch(bss)
-        nu = bss.n_unknowns
-        x_star = z_star[:, :nu]
-    bounds = None
-    if dt_policy == "spectral":
-        # one full spectral pass: its abscissa-aware dt drives the
-        # integration and its predicted settling step count sizes the
-        # sweep chunks (kernels launch over the predicted horizon
-        # instead of polling every 50 steps)
-        from repro.core import spectral
+    # the euler settle of one micro-batch: assembly, DC solve, sweep
+    with span("core.settle"):
+        if x_ref is not None:
+            # matrix-free fast path: ELL assembly, settle against the
+            # caller's reference — nothing (B, nz, nz) is ever built
+            bss = assemble_batch_ell(
+                nets, opamp, v_os=v_os, buffers=buffers, pattern=pattern
+            )
+            nu = bss.n_unknowns
+            x_star = np.asarray(x_ref, dtype=np.float64).reshape(len(nets), nu)
+            z_star = None
+        else:
+            bss = assemble_batch(
+                nets, opamp, v_os=v_os, buffers=buffers, pattern=pattern
+            )
+            # settle against the vmapped DC operating point
+            z_star = dc_solve_batch(bss)
+            nu = bss.n_unknowns
+            x_star = z_star[:, :nu]
+        bounds = None
+        if dt_policy == "spectral":
+            # one full spectral pass: its abscissa-aware dt drives the
+            # integration and its predicted settling step count sizes the
+            # sweep chunks (kernels launch over the predicted horizon
+            # instead of polling every 50 steps)
+            from repro.core import spectral
 
-        bounds = spectral.spectral_bounds(bss, rtol=params.settle_rtol)
-    steps, x_final, _res, dt = euler_settle_batch(
-        bss,
-        x_star,
-        rtol=params.settle_rtol,
-        atol=params.settle_atol,
-        max_steps=max_steps,
-        check_every=check_every,
-        interpret=interpret,
-        dt_policy=dt_policy,
-        bounds=bounds,
-        x0=x0,
-        sweep_dtype=sweep_dtype,
-    )
+            bounds = spectral.spectral_bounds(bss, rtol=params.settle_rtol)
+        steps, x_final, _res, dt = euler_settle_batch(
+            bss,
+            x_star,
+            rtol=params.settle_rtol,
+            atol=params.settle_atol,
+            max_steps=max_steps,
+            check_every=check_every,
+            interpret=interpret,
+            dt_policy=dt_policy,
+            bounds=bounds,
+            x0=x0,
+            sweep_dtype=sweep_dtype,
+        )
     tol = np.maximum(params.settle_rtol * np.abs(x_star), params.settle_atol)
     if sweep_dtype == "bfloat16":
         # same equilibrium-shift allowance the sweep loop applied

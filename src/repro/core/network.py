@@ -43,7 +43,7 @@ import functools
 
 import numpy as np
 
-from repro.analysis.runtime import sync_scope
+from repro.analysis.runtime import span
 from repro.core.specs import CircuitParams, DEFAULT_PARAMS
 from repro.core import transform as T
 
@@ -595,11 +595,13 @@ def build_proposed_batch(
     b = np.asarray(b, dtype=np.float64)
     b_count, n = b.shape
     fn = _batched_transform_2n(d_policy, beta, alpha, params)
-    # sanctioned host-build sync: the component extraction below is
-    # host-side numpy by design, so the transform outputs must
-    # materialize here — labeled net_build so SyncWatch attributes it
-    # to the build phase, not to the caller's dispatch scope
-    with sync_scope("net_build"):
+    # the Sec-IV transform and its sanctioned host-build sync: the
+    # component extraction below is host-side numpy by design, so the
+    # transform outputs must materialize here.  The span's net_build
+    # sync label charges that sync to the build, not to the caller's
+    # dispatch phase, and its time splits the transform from the
+    # extraction inside core.build_nets.
+    with span("core.transform", sync="net_build"):
         m_dc, k_s, sign = tuple(np.asarray(v) for v in fn(a, b))
     supply_g = np.concatenate([k_s, k_s], axis=1)
     supply_v = params.supply_v * np.concatenate([sign, -sign], axis=1)

@@ -33,6 +33,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro.analysis.runtime import span
 from repro.core import baselines, engine, refine as refine_mod
 from repro.core.network import (
     Netlist,
@@ -210,6 +211,7 @@ def _digital_resolve(
 PRECISION_PATHS = ("analog", "refined", "fallback", "unrefined")
 
 
+@span("core.refine")
 def _apply_graded_recovery(
     result: "BatchSolveResult",
     a: np.ndarray,
@@ -305,6 +307,7 @@ def _apply_graded_recovery(
     return result
 
 
+@span("core.build_nets")
 def _build_nets(
     a: np.ndarray,
     b: np.ndarray,
@@ -408,16 +411,17 @@ def _solve_batch_digital_submit(
     (the serving streams) — the jitted baselines dispatch async either
     way, and the returned handle materializes on ``wait()``.
     """
-    if device is not None:
-        aj = jax.device_put(a, device)
-        bj = jax.device_put(b, device)
-    else:
-        aj = jnp.asarray(a)
-        bj = jnp.asarray(b)
-        if mesh is not None:
-            from repro.distributed.sharding import shard_system_batch
+    with span("core.transfer"):
+        if device is not None:
+            aj = jax.device_put(a, device)
+            bj = jax.device_put(b, device)
+        else:
+            aj = jnp.asarray(a)
+            bj = jnp.asarray(b)
+    if mesh is not None:
+        from repro.distributed.sharding import shard_system_batch
 
-            aj, bj = shard_system_batch(aj, bj, mesh=mesh)
+        aj, bj = shard_system_batch(aj, bj, mesh=mesh)
 
     n_systems = a.shape[0]
     if method == "cholesky":

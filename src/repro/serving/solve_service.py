@@ -51,9 +51,9 @@ and keeps every device busy while the host builds the next one:
   ``inflight_per_device`` dispatched micro-batches (2 = classic double
   buffering; 1 degrades to the serial build→solve→unpack loop);
   harvest order is dispatch FIFO.  ``stats()`` splits the wall clock
-  into ``host_build_s`` / ``device_wait_s`` / ``unpack_s`` — on a
-  saturated stream the device wait is the residual the host could not
-  hide.
+  into ``host_build_s`` / ``device_wait_s`` / ``unpack_s`` (span
+  totals, see *Phases and spans* below) — on a saturated stream the
+  device wait is the residual the host could not hide.
 * **pattern reuse** — each bucket caches one stamp pattern, reused
   across micro-batches and streams.  ``analog_2n`` slot sets are
   normalized per ``(n, design)``, so the first derivation covers every
@@ -66,6 +66,42 @@ and keeps every device busy while the host builds the next one:
   per bucket: 1 for ``analog_2n`` buckets by construction, and for
   ``analog_n`` it stops climbing once the cached union covers the
   stream's slot population.
+
+Phases and spans
+----------------
+
+Every phase of the hot path is a :func:`repro.analysis.runtime.span`:
+a ``jax.profiler.TraceAnnotation`` on the profiler's host plane (on
+the device trace's clock, so a trace names the host work the device
+idles behind), a sync label for ``SyncWatch`` where the phase declares
+one, and a count and seconds in this service's own totals, which
+``drain()`` installs for its length (spans opened deep in ``core/``
+land on the service that caused them; two services never mix).  The
+span that is innermost at a moment names what the host was doing:
+
+* ``serve.drain`` — one ``drain()`` (sync label none);
+* ``serve.dispatch`` (label ``dispatch``) — one micro-batch's host
+  phase: ``serve.pad`` (pad and stack), ``core.build_nets`` (the
+  netlists: ``core.transform``, the Sec-IV transform and its
+  ``net_build``-labelled sync, then numpy extraction),
+  ``core.pattern`` (the cached stamp pattern's cover check or
+  derivation), ``core.assemble`` (the dense operator) and
+  ``core.transfer`` (the host-to-device copy) before the async solve;
+* ``serve.harvest`` (``harvest``) — the block on a micro-batch's DC
+  phase;
+* ``serve.finish`` (``finish``) — a deferred finish phase:
+  ``core.settle`` (the euler settle's reassembly, DC solve and sweep:
+  ``core.sweep_chunk`` per chunk launch, ``core.settle_poll`` per
+  convergence poll) and ``core.refine`` (graded recovery);
+* ``serve.unpack`` (``unpack``) — result slicing and acceptance.
+
+``stats["spans"]`` is ``{name: {"count", "s"}}``; the legacy keys
+``wall_s`` / ``host_build_s`` / ``device_wait_s`` / ``settle_finish_s``
+/ ``unpack_s`` are the totals of ``serve.drain`` / ``serve.dispatch``
+/ ``serve.harvest`` / ``serve.finish`` / ``serve.unpack``.
+``stats["queue_wait_s"]`` sums, over dispatched tickets, the time from
+``submit`` to the start of the first ``serve.dispatch`` that carried
+the ticket — the queueing a client's latency cannot split off.
 
 Failure semantics — the delivery contract
 -----------------------------------------
@@ -195,7 +231,7 @@ from typing import Any
 
 import numpy as np
 
-from repro.analysis.runtime import sync_scope
+from repro.analysis.runtime import SpanTotals, span
 from repro.core import engine
 from repro.core.operating_point import NonIdealities
 from repro.core.refine import as_refine_spec
@@ -305,6 +341,10 @@ class SolveTicket:
     priority: int = 0
     deadline: float | None = None
     seq: int = 0
+    # time.perf_counter() at submit, and at the start of the first
+    # serve.dispatch span that carried this ticket (the queue wait ends)
+    submitted_at: float = 0.0
+    dispatched_at: float | None = None
 
     @property
     def n(self) -> int:
@@ -494,11 +534,10 @@ class SolveService:
         self._pipelines: dict[tuple, _BucketPipeline] = {}
         self._next_rid = 0
         self._rr = 0             # round-robin stream cursor
-        self._wall_s = 0.0
-        self._host_build_s = 0.0
-        self._device_wait_s = 0.0
-        self._settle_finish_s = 0.0
-        self._unpack_s = 0.0
+        # this service's span totals (installed for each drain) and the
+        # summed submit-to-dispatch wait of its dispatched tickets
+        self._spans = SpanTotals()
+        self._queue_wait_s = 0.0
         self._real_sq = 0.0      # sum n^2 over served systems (stats)
         self._device_batches = [0] * len(self.devices)
         self._counters: dict[str, Any] = {
@@ -628,7 +667,8 @@ class SolveService:
         rid = self._next_rid
         self._next_rid += 1
         self.queue.push(
-            SolveTicket(rid=rid, a=a, b=b, sig=sig, x0=x0),
+            SolveTicket(rid=rid, a=a, b=b, sig=sig, x0=x0,
+                        submitted_at=time.perf_counter()),
             priority=priority, deadline=deadline,
         )
         return rid
@@ -666,15 +706,18 @@ class SolveService:
             a_pad, b_pad, sig.method, d_policy=sig.d_policy,
             beta=sig.beta, alpha=sig.alpha, params=self.params,
         )
-        if pipe.pattern is not None and engine.pattern_covers(pipe.pattern, nets):
-            return pipe.pattern, nets
-        union = engine.pattern_union(nets, sig.opamp)
-        pipe.pattern_derivations += 1
-        if pipe.pattern is None:
-            pipe.pattern = union
-        else:
-            pipe.pattern = engine.pattern_merge(pipe.pattern, union)
-            pipe.pattern_rebuilds += 1
+        with span("core.pattern"):
+            if pipe.pattern is not None and engine.pattern_covers(
+                pipe.pattern, nets
+            ):
+                return pipe.pattern, nets
+            union = engine.pattern_union(nets, sig.opamp)
+            pipe.pattern_derivations += 1
+            if pipe.pattern is None:
+                pipe.pattern = union
+            else:
+                pipe.pattern = engine.pattern_merge(pipe.pattern, union)
+                pipe.pattern_rebuilds += 1
         return pipe.pattern, nets
 
     def _dispatch_micro_batch(
@@ -689,20 +732,24 @@ class SolveService:
         the returned handle so they surface at harvest exactly where
         real ones would.
         """
-        t_build = time.perf_counter()
-        fault = (
-            None if self.fault_injector is None
-            else self.fault_injector.draw(dev=dev)
-        )
-        # sync_scope: any jax.Array materialization in here is a
-        # dispatch-phase sync — the runtime gate requires zero
-        try:
-            with sync_scope("dispatch"):
-                if fault is not None:
-                    self.fault_injector.build_fault(fault)  # raises build_error
-                sig = pipe.sig
-                n_real = len(tickets)
-                fill = self.batch_slots - n_real
+        # serve.dispatch's sync label: any jax.Array materialization in
+        # here is a dispatch-phase sync — the runtime gate requires zero
+        with span("serve.dispatch", sync="dispatch"):
+            t_start = time.perf_counter()
+            for t in tickets:
+                if t.dispatched_at is None:
+                    t.dispatched_at = t_start
+                    self._queue_wait_s += t_start - t.submitted_at
+            fault = (
+                None if self.fault_injector is None
+                else self.fault_injector.draw(dev=dev)
+            )
+            if fault is not None:
+                self.fault_injector.build_fault(fault)  # raises build_error
+            sig = pipe.sig
+            n_real = len(tickets)
+            fill = self.batch_slots - n_real
+            with span("serve.pad"):
                 rhs = "zero" if sig.method in DIGITAL_METHODS else "supply"
                 padded = [
                     pad_system(t.a, t.b, pipe.n_pad, rhs=rhs) for t in tickets
@@ -728,33 +775,31 @@ class SolveService:
                     rows += [rows[-1]] * fill
                     settle_x0 = np.stack(rows)
 
-                pattern, nets = self._bucket_pattern(pipe, a_stack, b_stack)
-                pending = solve_batch_submit(
-                    a_stack,
-                    b_stack,
-                    method=sig.method,
-                    opamp=sig.opamp,
-                    nonideal=sig.nonideal,
-                    nets=nets,
-                    d_policy=sig.d_policy,
-                    beta=sig.beta,
-                    alpha=sig.alpha,
-                    compute_settling=sig.compute_settling,
-                    settle_method=sig.settle_method,
-                    settle_max_steps=sig.settle_max_steps,
-                    settle_dt_policy=sig.settle_dt_policy,
-                    tol=sig.tol,
-                    max_iter=sig.max_iter,
-                    fallback=self.fallback,
-                    fallback_residual_tol=self.fallback_residual_tol,
-                    refine=self.refine,
-                    sweep_dtype=sig.sweep_dtype,
-                    settle_x0=settle_x0,
-                    pattern=pattern,
-                    device=self.devices[dev],
-                )
-        finally:
-            self._host_build_s += time.perf_counter() - t_build
+            pattern, nets = self._bucket_pattern(pipe, a_stack, b_stack)
+            pending = solve_batch_submit(
+                a_stack,
+                b_stack,
+                method=sig.method,
+                opamp=sig.opamp,
+                nonideal=sig.nonideal,
+                nets=nets,
+                d_policy=sig.d_policy,
+                beta=sig.beta,
+                alpha=sig.alpha,
+                compute_settling=sig.compute_settling,
+                settle_method=sig.settle_method,
+                settle_max_steps=sig.settle_max_steps,
+                settle_dt_policy=sig.settle_dt_policy,
+                tol=sig.tol,
+                max_iter=sig.max_iter,
+                fallback=self.fallback,
+                fallback_residual_tol=self.fallback_residual_tol,
+                refine=self.refine,
+                sweep_dtype=sig.sweep_dtype,
+                settle_x0=settle_x0,
+                pattern=pattern,
+                device=self.devices[dev],
+            )
         if fault is not None:
             pending = self.fault_injector.arm(pending, fault)
         pipe.micro_batches += 1
@@ -961,12 +1006,10 @@ class SolveService:
         acceptance immediately (non-finite / uncertified tickets
         re-enter the retry loop individually).
         """
-        t_wait = time.perf_counter()
         try:
-            with sync_scope("harvest"):
+            with span("serve.harvest", sync="harvest"):
                 batch = flight.pending.wait_dc()
         except Exception as exc:
-            self._device_wait_s += time.perf_counter() - t_wait
             per_dev[flight.dev] -= 1
             tripped = self.breaker.record_failure(flight.dev)
             self._group_failed(
@@ -976,7 +1019,6 @@ class SolveService:
             if tripped:
                 self._quarantine(flight.dev, inflight, per_dev, work)
             return
-        self._device_wait_s += time.perf_counter() - t_wait
         per_dev[flight.dev] -= 1
         self.breaker.record_success(flight.dev)
         if flight.pending.split:
@@ -994,29 +1036,24 @@ class SolveService:
         ``device_fault``) but never to the stream's breaker: the
         stream did its job, the post-DC analysis failed.
         """
-        t_finish = time.perf_counter()
         try:
-            with sync_scope("finish"):
+            with span("serve.finish", sync="finish"):
                 batch = flight.pending.wait()
         except Exception as exc:
-            self._settle_finish_s += time.perf_counter() - t_finish
             self._group_failed(
                 flight.pipe, flight.tickets, exc,
                 device_side=True, work=work, out=out,
             )
             return
-        self._settle_finish_s += time.perf_counter() - t_finish
         self._deliver(flight, batch, out, work)
 
     def _deliver(self, flight: _InFlight, batch, out, work) -> None:
         """Delivery acceptance for one harvested micro-batch: unpack,
         hand out terminal answers, route rejected tickets to retry."""
-        t_unpack = time.perf_counter()
-        with sync_scope("unpack"):
+        with span("serve.unpack", sync="unpack"):
             bad = self._unpack_micro_batch(
                 flight.pipe, flight.tickets, batch, injected=flight.injected
             )
-        self._unpack_s += time.perf_counter() - t_unpack
         for t in flight.tickets:
             if t.result is not None:
                 out[t.rid] = t.result
@@ -1062,100 +1099,103 @@ class SolveService:
         ticket is re-queued at its original admission rank — already
         answered ones re-deliver from their result slot next drain.
         """
-        t0 = time.perf_counter()
         popped = self.queue.pop_all()
         if not popped:
             return {}
-        out: dict[int, SolveResult | SolveError] = {}
+        # every span opened below, in core/ too, lands on this service
+        with self._spans.installed(), span("serve.drain"):
+            out: dict[int, SolveResult | SolveError] = {}
 
-        queued = popped
-        if (
-            self.max_queue_depth is not None
-            and len(queued) > self.max_queue_depth
-        ):
-            # load shedding: lowest admission rank (lowest priority /
-            # latest deadline / newest) drops first
-            queued, shed = (
-                queued[: self.max_queue_depth],
-                queued[self.max_queue_depth:],
-            )
-            self._counters["shed"] += len(shed)
-            for ticket in shed:
-                self._fail(ticket, "shed",
-                           f"queue depth over {self.max_queue_depth}", out)
+            queued = popped
+            if (
+                self.max_queue_depth is not None
+                and len(queued) > self.max_queue_depth
+            ):
+                # load shedding: lowest admission rank (lowest priority /
+                # latest deadline / newest) drops first
+                queued, shed = (
+                    queued[: self.max_queue_depth],
+                    queued[self.max_queue_depth:],
+                )
+                self._counters["shed"] += len(shed)
+                for ticket in shed:
+                    self._fail(ticket, "shed",
+                               f"queue depth over {self.max_queue_depth}", out)
 
-        buckets: dict[tuple, list[SolveTicket]] = {}
-        for ticket in queued:
-            buckets.setdefault(self._bucket_key(ticket), []).append(ticket)
+            buckets: dict[tuple, list[SolveTicket]] = {}
+            for ticket in queued:
+                buckets.setdefault(self._bucket_key(ticket), []).append(ticket)
 
-        # fixed-shape micro-batch groups, bucket-major in admission
-        # order of each bucket's head request; retries/bisections
-        # re-enter at the FRONT so old work finishes first
-        work: collections.deque = collections.deque()
-        for key, tickets in buckets.items():
-            n_pad, sig = key
-            pipe = self._pipelines.setdefault(
-                key, _BucketPipeline(n_pad=n_pad, sig=sig)
-            )
-            for start in range(0, len(tickets), self.batch_slots):
-                work.append((pipe, tickets[start:start + self.batch_slots]))
-
-        inflight: list[_InFlight] = []          # dispatch-FIFO harvest order
-        finishing: list[_InFlight] = []         # DC done, settle/fallback due
-        per_dev = [0] * len(self.devices)
-        # deterministic placement per drain: identical request streams
-        # hit identical (bucket, device) pairs every drain, so a warmed
-        # service never recompiles (jit executables are per device)
-        self._rr = 0
-        try:
-            while work or inflight or finishing:
-                if work:
-                    pipe, group = work.popleft()
-                    group = [t for t in group if self._admit_ticket(t, out)]
-                    if not group:
-                        continue
-                    dev = self._next_stream(per_dev)
-                    if dev is not None:
-                        try:
-                            flight = self._dispatch_micro_batch(
-                                pipe, group, dev
-                            )
-                        except Exception as exc:
-                            # host build failure: no device verdict —
-                            # hand back a consumed probe slot unjudged
-                            self.breaker.release(dev)
-                            self._group_failed(
-                                pipe, group, exc,
-                                device_side=False, work=work, out=out,
-                            )
-                        else:
-                            inflight.append(flight)
-                            per_dev[dev] += 1
-                        continue
-                    work.appendleft((pipe, group))
-                if inflight:
-                    self._harvest(
-                        inflight.pop(0), out, per_dev, work, inflight,
-                        finishing,
+            # fixed-shape micro-batch groups, bucket-major in admission
+            # order of each bucket's head request; retries/bisections
+            # re-enter at the FRONT so old work finishes first
+            work: collections.deque = collections.deque()
+            for key, tickets in buckets.items():
+                n_pad, sig = key
+                pipe = self._pipelines.setdefault(
+                    key, _BucketPipeline(n_pad=n_pad, sig=sig)
+                )
+                for start in range(0, len(tickets), self.batch_slots):
+                    work.append(
+                        (pipe, tickets[start:start + self.batch_slots])
                     )
-                elif finishing:
-                    # streams idle (or blocked): run deferred finish
-                    # phases — settle sweeps whose DC harvest already
-                    # freed their stream slot
-                    self._finish_flight(finishing.pop(0), out, work)
-                elif work:
-                    # every stream quarantined with backoff pending:
-                    # degrade to probing, never to a deadlock
-                    self.breaker.force_probe()
-        except BaseException:
-            # unexpected interruption: the caller receives nothing, so
-            # put EVERY popped ticket back at its original admission
-            # rank — answered ones re-deliver from their result slot
-            # next drain, nothing is silently discarded
-            self.queue.requeue(popped)
-            self._wall_s += time.perf_counter() - t0
-            raise
-        self._wall_s += time.perf_counter() - t0
+
+            inflight: list[_InFlight] = []      # dispatch-FIFO harvest order
+            finishing: list[_InFlight] = []     # DC done, settle/fallback due
+            per_dev = [0] * len(self.devices)
+            # deterministic placement per drain: identical request streams
+            # hit identical (bucket, device) pairs every drain, so a warmed
+            # service never recompiles (jit executables are per device)
+            self._rr = 0
+            try:
+                while work or inflight or finishing:
+                    if work:
+                        pipe, group = work.popleft()
+                        group = [
+                            t for t in group if self._admit_ticket(t, out)
+                        ]
+                        if not group:
+                            continue
+                        dev = self._next_stream(per_dev)
+                        if dev is not None:
+                            try:
+                                flight = self._dispatch_micro_batch(
+                                    pipe, group, dev
+                                )
+                            except Exception as exc:
+                                # host build failure: no device verdict —
+                                # hand back a consumed probe slot unjudged
+                                self.breaker.release(dev)
+                                self._group_failed(
+                                    pipe, group, exc,
+                                    device_side=False, work=work, out=out,
+                                )
+                            else:
+                                inflight.append(flight)
+                                per_dev[dev] += 1
+                            continue
+                        work.appendleft((pipe, group))
+                    if inflight:
+                        self._harvest(
+                            inflight.pop(0), out, per_dev, work, inflight,
+                            finishing,
+                        )
+                    elif finishing:
+                        # streams idle (or blocked): run deferred finish
+                        # phases — settle sweeps whose DC harvest already
+                        # freed their stream slot
+                        self._finish_flight(finishing.pop(0), out, work)
+                    elif work:
+                        # every stream quarantined with backoff pending:
+                        # degrade to probing, never to a deadlock
+                        self.breaker.force_probe()
+            except BaseException:
+                # unexpected interruption: the caller receives nothing, so
+                # put EVERY popped ticket back at its original admission
+                # rank — answered ones re-deliver from their result slot
+                # next drain, nothing is silently discarded
+                self.queue.requeue(popped)
+                raise
         return out
 
     # ----------------------------------------------------------- sessions
@@ -1185,7 +1225,16 @@ class SolveService:
         time the overlapped host phases could not hide, and
         ``settle_finish_s`` the deferred finish phases (settle sweep +
         fallback) run after their stream slot was released.
-        ``pattern_derivations`` counts
+        The four, and ``wall_s``, are the totals of the spans
+        ``serve.dispatch`` / ``serve.harvest`` / ``serve.finish`` /
+        ``serve.unpack`` and ``serve.drain``.  ``spans`` holds every
+        span this service's drains opened, those of ``core/`` included
+        (``core.build_nets``, ``core.assemble``, ``core.transfer``,
+        ``core.settle_poll`` whose count is the settle polls, ...; see
+        the module docstring's *Phases and spans*), as
+        ``{name: {"count": int, "s": float}}``.  ``queue_wait_s`` sums
+        each dispatched ticket's wait from ``submit`` to the start of
+        its first ``serve.dispatch``.  ``pattern_derivations`` counts
         ``pattern_union`` calls per bucket (1 proves the cache served
         every later micro-batch on every stream).
 
@@ -1231,16 +1280,19 @@ class SolveService:
             pad_sq += (pipe.systems + pipe.fill_slots) * float(n_pad) ** 2
         real_sq = self._real_sq
         c = self._counters
+        spans = self._spans
         return {
             "requests": total,
             "fill_slots": fills,
             "buckets": per_bucket,
             "pad_overhead": pad_sq / real_sq if real_sq else 1.0,
-            "wall_s": self._wall_s,
-            "host_build_s": self._host_build_s,
-            "device_wait_s": self._device_wait_s,
-            "settle_finish_s": self._settle_finish_s,
-            "unpack_s": self._unpack_s,
+            "wall_s": spans.seconds("serve.drain"),
+            "host_build_s": spans.seconds("serve.dispatch"),
+            "device_wait_s": spans.seconds("serve.harvest"),
+            "settle_finish_s": spans.seconds("serve.finish"),
+            "unpack_s": spans.seconds("serve.unpack"),
+            "queue_wait_s": self._queue_wait_s,
+            "spans": spans.snapshot(),
             "devices": len(self.devices),
             "device_micro_batches": list(self._device_batches),
             "inflight_per_device": self.inflight_per_device,

@@ -28,7 +28,7 @@ from repro.analysis import (
     json_report,
     load_baseline,
     parse_suppressions,
-    sync_scope,
+    span,
     write_baseline,
 )
 from repro.analysis.runtime import _SCOPE_STACK
@@ -501,7 +501,7 @@ def test_sync_watch_attributes_syncs_to_scope():
     y = jnp.arange(3.0)
     with SyncWatch() as watch:
         np.asarray(y)                       # ambient
-        with sync_scope("harvest"):
+        with span("serve.harvest", sync="harvest"):
             xs = np.asarray(y)
             float(xs[0])                    # numpy operand: not counted
         np.asarray(np.arange(3.0))          # numpy operand: not counted
@@ -510,7 +510,7 @@ def test_sync_watch_attributes_syncs_to_scope():
     assert watch.total("harvest") == 1
     # patches restored, scope stack balanced
     assert _SCOPE_STACK == ["ambient"]
-    with sync_scope("x"):
+    with span("serve.x", sync="x"):
         assert _SCOPE_STACK[-1] == "x"
     assert _SCOPE_STACK == ["ambient"]
 
