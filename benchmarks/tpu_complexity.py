@@ -216,8 +216,8 @@ def dense_vs_ell(
 ) -> dict:
     """Wall-clock speedup of the matrix-free path over the dense sweep
     at the largest size the dense *fused* kernel still handles
-    (``SWEEP_STATE_LIMIT``); beyond it the dense path degrades to
-    per-step launches and stops being a usable baseline at all.
+    (``SWEEP_STATE_LIMIT``); beyond it the dense path streams the whole
+    operator from HBM every step and stops being a usable baseline.
     """
     rng = np.random.default_rng(55)
     nets, x, density = _sparse_systems(rng, n, count)

@@ -1592,9 +1592,9 @@ def euler_settle_batch(
         )
 
     # hoist the kernel-shape prep out of the chunk loop: block-pad once
-    # and pre-transpose for the VMEM-resident sweep kernel
+    # (both dense paths) and pre-transpose for the VMEM-resident sweep
     fused = nz <= SWEEP_STATE_LIMIT
-    size = nz + (-nz) % 128 if fused else nz
+    size = nz + (-nz) % 128
     if size != nz:
         mt = np.pad(mt, ((0, 0), (0, size - nz), (0, size - nz)))
         ct = np.pad(ct, ((0, 0), (0, size - nz)))

@@ -173,8 +173,8 @@ def sweep_backend(nz: int, k: int | None) -> str:
 
     ``k`` is the ELL slot width (None for a dense-only caller).
     Returns ``"ell"`` (ELL-SpMV sweep), ``"dense"`` (fused dense sweep,
-    operator VMEM-resident) or ``"dense-step"`` (tiled dense per-step
-    kernel).
+    operator VMEM-resident) or ``"dense-step"`` (tiled dense step
+    kernel, looped on the device).
     """
     if k is not None and k < ELL_FILL_CUTOFF * nz:
         return "ell"
@@ -254,8 +254,10 @@ def transient_sweep(
     """``n_steps`` fused batched Euler steps; m (B, n, n), z/c (B, n).
 
     Uses the VMEM-resident sweep kernel while the per-system operator
-    fits on-chip, else falls back to ``n_steps`` launches of the tiled
-    batched step kernel.  Returns ``(z', res)`` with the per-system
+    fits on-chip, else falls back to the tiled batched step kernel
+    looped on the device (:func:`repro.kernels.transient_step.
+    tiled_transient_sweep_pallas`).  Either way one call is one launch,
+    whatever ``n_steps``.  Returns ``(z', res)`` with the per-system
     residual ``max_i |M z' + c|_i`` evaluated at the final state.
 
     ``m_transposed=True`` asserts the caller already block-padded every
@@ -281,18 +283,14 @@ def transient_sweep(
         )
         return out[:, 0], jnp.max(jnp.abs(dz), axis=(1, 2))
     if n > SWEEP_STATE_LIMIT:
-        # pad once so the per-step wrapper's _pad_to is a no-op view
         bm, bk = _st.DEFAULT_BATCHED_BLOCK
         size = n + (-n) % math.lcm(bm, bk)
-        m = _pad_to(m, (1, size, size))
-        z = _pad_to(z, (1, size))
-        c = _pad_to(c, (1, size))
-        for _ in range(n_steps):
-            z, _ = transient_step_batched(m, z, c, dt, interpret=interpret)
-        # dt=0 step: state unchanged, residual evaluated at the *final*
-        # state — matching the fused kernel's contract
-        _zf, res = transient_step_batched(m, z, c, 0.0, interpret=interpret)
-        return z[:, :n], res
+        out, res = _st.tiled_transient_sweep_pallas(
+            _pad_to(m, (1, size, size)), _pad_to(z, (1, size))[:, None, :],
+            _pad_to(c, (1, size))[:, None, :], jnp.int32(n_steps), dt=dt,
+            interpret=interpret,
+        )
+        return out[:, 0, :n], res
     size = n + (-n) % 128
     mp = _pad_to(m, (1, size, size))
     zp = _pad_to(z, (1, size))

@@ -27,8 +27,12 @@ Batched variants (one state vector per system, per-system operator):
   whole per-system operator VMEM-resident (grid over the batch only):
   the physics iterates on-chip and M crosses HBM once per *chunk*
   instead of once per step.  Usable while the double-buffered
-  ``n^2 * 4``-byte operator fits in VMEM (``sweep_vmem_bytes``); the
-  engine falls back to the tiled per-step kernel beyond.
+  ``n^2 * 4``-byte operator fits in VMEM (``sweep_vmem_bytes``).
+* :func:`tiled_transient_sweep_pallas` — the fallback beyond that:
+  ``n_steps`` launches of the tiled batched step kernel inside one
+  compiled device program (a device-side loop), so a chunk costs the
+  host one dispatch however many steps it runs.  M crosses HBM once
+  per step.
 
 The batched kernels run their matvecs on the MXU at ``HIGHEST``
 precision (f32 semantics; the default single bf16 pass would move the
@@ -300,3 +304,37 @@ def transient_sweep_pallas(
         name="dense_sweep",
         interpret=interpret,
     )(m_t, z, c)
+
+
+# ---------------------------------------------------------------------------
+# Tiled multi-step sweep: the per-step kernel under a device-side loop
+# ---------------------------------------------------------------------------
+
+
+@functools.partial(jax.jit, static_argnames=("dt", "interpret"))
+def tiled_transient_sweep_pallas(
+    m: jnp.ndarray,
+    z: jnp.ndarray,
+    c: jnp.ndarray,
+    n_steps,
+    *,
+    dt: float = 1.0,
+    interpret: bool = False,
+) -> tuple[jnp.ndarray, jnp.ndarray]:
+    """``n_steps`` tiled Euler steps per system in one dispatch.
+
+    ``m`` is ``(B, n, n)`` (not transposed), ``z``/``c`` ``(B, 1, n)``,
+    ``n`` a multiple of the default blocks.  Each step is one launch of
+    :func:`transient_step_batched_pallas`, looped on the device.
+    ``n_steps`` is a traced count, so every chunk length shares one
+    executable.  Returns ``(z', res)`` with ``res[b] = max_i |M_b z'_b
+    + c_b|_i`` — the settling-check reduction at the final state, from
+    one more ``dt = 0`` pass.
+    """
+    def body(_, zz):
+        return transient_step_batched_pallas(m, zz, c, dt,
+                                             interpret=interpret)[0]
+
+    z = jax.lax.fori_loop(0, n_steps, body, z)
+    _, dz = transient_step_batched_pallas(m, z, c, 0.0, interpret=interpret)
+    return z, jnp.max(jnp.abs(dz), axis=(1, 2))

@@ -107,6 +107,21 @@ def test_dense_step_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
+def test_tiled_sweep_compiles(one_chip):
+    """The dense-step path's chunk program: the tiled kernel under a
+    device-side loop with a traced step count."""
+    from repro.kernels.transient_step import tiled_transient_sweep_pallas
+
+    state = (BATCH, 1, DENSE_STEP_NZ)
+    text = _compile(
+        lambda m, z, c, n: tiled_transient_sweep_pallas(m, z, c, n),
+        _spec(one_chip, (BATCH, DENSE_STEP_NZ, DENSE_STEP_NZ), jnp.float32),
+        _spec(one_chip, state, jnp.float32), _spec(one_chip, state, jnp.float32),
+        _spec(one_chip, (), jnp.int32),
+    )
+    assert "tpu_custom_call" in text
+
+
 def test_dc_solve_compiles_without_f64_lu(one_chip):
     """The operating point at the n=192 analog_2n bucket, in the donated
     form the device streams run: f64 LU is unimplemented on TPU, so the
