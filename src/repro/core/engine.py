@@ -1531,90 +1531,88 @@ def euler_settle_batch(
         else None
     )
 
-    if bounds is not None and dt_policy == "spectral":
-        # re-apply the caller's safety factor to the (factor-free)
-        # stability limit — a precomputed bounds must not pin dt to the
-        # dt_safety it happened to be computed with
-        dt = dt_safety * np.asarray(bounds.dt_limit)        # (B,)
-    else:
-        dt = _settle_dt(bss, dt_safety, dt_policy)          # (B,)
-    if check_every is None:
-        if bounds is not None:
-            predicted = bounds.settle_steps
-            if getattr(bounds, "slow_basis", None) is not None:
-                from repro.core import spectral
-
-                z_err = (z0_full if z0_full is not None else 0.0) \
-                    - _embed(x_ref)
-                predicted = spectral.amplitude_settle_steps(
-                    bounds, z_err, rtol=rtol,
-                    x_scale=np.max(np.abs(x_ref), axis=1),
-                )
-            check_every = sweep_chunk_schedule(predicted, max_steps)
+    # the host work between the DC point and the first chunk: the step
+    # size, the dt fold and narrowing of the operator, its kernel layout
+    # and upload (``core.transfer`` nests inside on the dense path)
+    with span("core.settle_prep"):
+        if bounds is not None and dt_policy == "spectral":
+            # re-apply the caller's safety factor to the (factor-free)
+            # stability limit — a precomputed bounds must not pin dt to
+            # the dt_safety it happened to be computed with
+            dt = dt_safety * np.asarray(bounds.dt_limit)    # (B,)
         else:
-            check_every = 50
+            dt = _settle_dt(bss, dt_safety, dt_policy)      # (B,)
+        if check_every is None:
+            if bounds is not None:
+                predicted = bounds.settle_steps
+                if getattr(bounds, "slow_basis", None) is not None:
+                    from repro.core import spectral
 
-    if isinstance(bss, EllBatchedStateSpace):
-        # hoist the kernel layout out of the chunk loop: dt-folded,
-        # slot-major and lane-padded once per sweep
-        idx, wt, ct = ell_kernel_operands(
-            bss.indices, bss.weights * dt[:, None, None],
-            bss.c * dt[:, None], sweep_dtype,
-        )
-        size = idx.shape[2]
-        if z0_full is not None:
-            z = jnp.asarray(np.pad(
-                z0_full, ((0, 0), (0, size - nz))).astype(np.float32))
-        else:
-            z = jnp.zeros((b_count, size), dtype=jnp.float32)
+                    z_err = (z0_full if z0_full is not None else 0.0) \
+                        - _embed(x_ref)
+                    predicted = spectral.amplitude_settle_steps(
+                        bounds, z_err, rtol=rtol,
+                        x_scale=np.max(np.abs(x_ref), axis=1),
+                    )
+                check_every = sweep_chunk_schedule(predicted, max_steps)
+            else:
+                check_every = 50
 
-        def step_chunk(zz, n):
-            return ell_transient_sweep(
-                idx, wt, zz, ct, n_steps=n, interpret=interpret,
-                padded=True, sweep_dtype=sweep_dtype,
+        if isinstance(bss, EllBatchedStateSpace):
+            # hoist the kernel layout out of the chunk loop: dt-folded,
+            # slot-major and lane-padded once per sweep
+            idx, wt, ct = ell_kernel_operands(
+                bss.indices, bss.weights * dt[:, None, None],
+                bss.c * dt[:, None], sweep_dtype,
             )
+            size = idx.shape[2]
+            if z0_full is not None:
+                z = jnp.asarray(np.pad(
+                    z0_full, ((0, 0), (0, size - nz))).astype(np.float32))
+            else:
+                z = jnp.zeros((b_count, size), dtype=jnp.float32)
 
-        steps, x_final, res = _settle_loop(
-            step_chunk, z, dt, x_ref, rtol=rtol, atol=atol,
-            check_every=check_every, max_steps=max_steps,
-            tol_floor=tol_floor,
-        )
-        return steps, x_final, res, dt
-
-    mt = (bss.m * dt[:, None, None]).astype(np.float32)
-    ct = (bss.c * dt[:, None]).astype(np.float32)
-    if sweep_dtype == "bfloat16":
-        # bf16 storage semantics on the dense path: round the folded
-        # operator through bf16 once, outside the chunk loop (the dense
-        # kernels accumulate in f32 regardless)
-        mt = np.asarray(
-            jnp.asarray(mt).astype(jnp.bfloat16).astype(jnp.float32)
-        )
-
-    # hoist the kernel-shape prep out of the chunk loop: block-pad once
-    # (both dense paths) and pre-transpose for the VMEM-resident sweep
-    fused = nz <= SWEEP_STATE_LIMIT
-    size = nz + (-nz) % 128
-    if size != nz:
-        mt = np.pad(mt, ((0, 0), (0, size - nz), (0, size - nz)))
-        ct = np.pad(ct, ((0, 0), (0, size - nz)))
-    if fused:
-        mt = mt.transpose(0, 2, 1)
-
-    with span("core.transfer"):
-        if z0_full is not None:
-            z = jnp.asarray(np.pad(
-                z0_full, ((0, 0), (0, size - nz))).astype(np.float32))
+            def step_chunk(zz, n):
+                return ell_transient_sweep(
+                    idx, wt, zz, ct, n_steps=n, interpret=interpret,
+                    padded=True, sweep_dtype=sweep_dtype,
+                )
         else:
-            z = jnp.zeros((b_count, size), dtype=jnp.float32)
-        mt_j = jnp.asarray(np.ascontiguousarray(mt))
-        ct_j = jnp.asarray(ct)
+            mt = (bss.m * dt[:, None, None]).astype(np.float32)
+            ct = (bss.c * dt[:, None]).astype(np.float32)
+            if sweep_dtype == "bfloat16":
+                # bf16 storage semantics on the dense path: round the
+                # folded operator through bf16 once, outside the chunk
+                # loop (the dense kernels accumulate in f32 regardless)
+                mt = np.asarray(
+                    jnp.asarray(mt).astype(jnp.bfloat16).astype(jnp.float32)
+                )
 
-    def step_chunk(zz, n):
-        return transient_sweep(
-            mt_j, zz, ct_j, n_steps=n, interpret=interpret,
-            m_transposed=fused,
-        )
+            # hoist the kernel-shape prep out of the chunk loop:
+            # block-pad once (both dense paths) and pre-transpose for
+            # the VMEM-resident sweep
+            fused = nz <= SWEEP_STATE_LIMIT
+            size = nz + (-nz) % 128
+            if size != nz:
+                mt = np.pad(mt, ((0, 0), (0, size - nz), (0, size - nz)))
+                ct = np.pad(ct, ((0, 0), (0, size - nz)))
+            if fused:
+                mt = mt.transpose(0, 2, 1)
+
+            with span("core.transfer"):
+                if z0_full is not None:
+                    z = jnp.asarray(np.pad(
+                        z0_full, ((0, 0), (0, size - nz))).astype(np.float32))
+                else:
+                    z = jnp.zeros((b_count, size), dtype=jnp.float32)
+                mt_j = jnp.asarray(np.ascontiguousarray(mt))
+                ct_j = jnp.asarray(ct)
+
+            def step_chunk(zz, n):
+                return transient_sweep(
+                    mt_j, zz, ct_j, n_steps=n, interpret=interpret,
+                    m_transposed=fused,
+                )
 
     steps, x_final, res = _settle_loop(
         step_chunk, z, dt, x_ref, rtol=rtol, atol=atol,

@@ -117,6 +117,18 @@ class Netlist:
         return int(self.branch_g.shape[0])
 
     @property
+    def n_cross_branches(self) -> int:
+        """Branches between node ``i`` of the ``x`` half and node
+        ``n + j`` (``j != i``) of the ``-x`` half: the off-diagonals of
+        ``K_B``, which an M-matrix never stamps (proposed design only)."""
+        if self.n_nodes != 2 * self.n_unknowns:
+            return 0
+        n = self.n_unknowns
+        lo = np.minimum(self.branch_i, self.branch_j)
+        hi = np.maximum(self.branch_i, self.branch_j)
+        return int(np.count_nonzero((lo < n) & (hi >= n) & (hi - n != lo)))
+
+    @property
     def is_passive(self) -> bool:
         return self.n_cells == 0
 

@@ -91,6 +91,8 @@ span that is innermost at a moment names what the host was doing:
   phase;
 * ``serve.finish`` (``finish``) — a deferred finish phase:
   ``core.settle`` (the euler settle's reassembly, DC solve and sweep:
+  ``core.settle_prep`` once, the host work between the DC point and
+  the first chunk — step size, dt fold, float32 cast, upload — then
   ``core.sweep_chunk`` per chunk launch, ``core.settle_poll`` per
   convergence poll) and ``core.refine`` (graded recovery);
 * ``serve.unpack`` (``unpack``) — result slicing and acceptance.
@@ -102,6 +104,15 @@ span that is innermost at a moment names what the host was doing:
 ``stats["queue_wait_s"]`` sums, over dispatched tickets, the time from
 ``submit`` to the start of the first ``serve.dispatch`` that carried
 the ticket — the queueing a client's latency cannot split off.
+
+Three counters say what the circuit asked of the hardware:
+``stats["neg_cells"]`` and ``stats["cross_branches"]`` sum, over the
+dispatched tickets of analog micro-batches, the negative-resistance
+cells stamped and the crosspoint branches between the ``x`` and ``-x``
+halves (the off-diagonals of ``K_B``; none for an M-matrix), and
+``stats["settle_steps_swept"]`` the Euler steps the device ran, per
+micro-batch up to its slowest system (pad rows included), over the
+unpacked micro-batches of the euler settle.
 
 Failure semantics — the delivery contract
 -----------------------------------------
@@ -555,6 +566,9 @@ class SolveService:
             "quarantines": 0,
             "requeued_on_quarantine": 0,
             "errors": {k: 0 for k in ERROR_KINDS},
+            "neg_cells": 0,
+            "cross_branches": 0,
+            "settle_steps_swept": 0,
         }
 
     @staticmethod
@@ -776,6 +790,9 @@ class SolveService:
                     settle_x0 = np.stack(rows)
 
             pattern, nets = self._bucket_pattern(pipe, a_stack, b_stack)
+            for net in (nets or [])[:n_real]:
+                self._counters["neg_cells"] += net.n_cells
+                self._counters["cross_branches"] += net.n_cross_branches
             pending = solve_batch_submit(
                 a_stack,
                 b_stack,
@@ -847,6 +864,13 @@ class SolveService:
             None if batch.settle_time is None
             else np.asarray(batch.settle_time)[:n_real].tolist()
         )
+        if batch.info.get("settle_method") == "euler":
+            # the sweep runs until the slowest system settles or the
+            # budget ends, so the largest step count of the micro-batch
+            # is the Euler steps the device ran
+            self._counters["settle_steps_swept"] += int(
+                np.max(batch.info["settle_steps"])
+            )
         cols: dict[str, list] = {}
         shared: dict[str, Any] = {}
         for key, v in batch.info.items():
@@ -1234,7 +1258,9 @@ class SolveService:
         the module docstring's *Phases and spans*), as
         ``{name: {"count": int, "s": float}}``.  ``queue_wait_s`` sums
         each dispatched ticket's wait from ``submit`` to the start of
-        its first ``serve.dispatch``.  ``pattern_derivations`` counts
+        its first ``serve.dispatch``.  ``neg_cells`` /
+        ``cross_branches`` / ``settle_steps_swept`` are the circuit
+        counters of the module docstring.  ``pattern_derivations`` counts
         ``pattern_union`` calls per bucket (1 proves the cache served
         every later micro-batch on every stream).
 
@@ -1293,6 +1319,9 @@ class SolveService:
             "unpack_s": spans.seconds("serve.unpack"),
             "queue_wait_s": self._queue_wait_s,
             "spans": spans.snapshot(),
+            "neg_cells": c["neg_cells"],
+            "cross_branches": c["cross_branches"],
+            "settle_steps_swept": c["settle_steps_swept"],
             "devices": len(self.devices),
             "device_micro_batches": list(self._device_batches),
             "inflight_per_device": self.inflight_per_device,
