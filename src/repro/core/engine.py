@@ -18,11 +18,13 @@ factors the physics into
   given system does not populate stamps ``w = 0``: the amp dynamics
   remain (a stable, decoupled subsystem) but inject no current and load
   no node capacitance, so the node physics match the per-system
-  assembly exactly.  Two products share one value-gathering pass:
+  assembly exactly.  Three products share one value-gathering pass:
 
   - :func:`assemble_batch` — the dense ``(B, nz, nz)`` operators
-    (vectorized ``np.add.at``), needed by the direct DC solve and the
-    exact eig path;
+    (vectorized ``np.add.at``) on the host, for the settle and exact
+    eig paths;
+  - :func:`assemble_batch_device` — the same dense operators built on
+    the device from the ELL scatter below, for the DC solve;
   - :func:`assemble_batch_ell` — the **matrix-free path**: a jitted
     ``jnp`` scatter builds per-row ``(indices, weights)`` ELL arrays
     directly on device (bounded row degree from the pattern: 1 diagonal
@@ -395,10 +397,15 @@ def pattern_merge(a: StampPattern, b: StampPattern) -> StampPattern:
 
 @dataclasses.dataclass
 class BatchedStateSpace:
-    """``dz/dt = M_b z + c_b`` for a batch of B systems on one pattern."""
+    """``dz/dt = M_b z + c_b`` for a batch of B systems on one pattern.
 
-    m: np.ndarray                # (B, nz, nz) float64
-    c: np.ndarray                # (B, nz)
+    ``m`` and ``c`` are host arrays from :func:`assemble_batch` (the
+    settle path, the transient paths) and device arrays from
+    :func:`assemble_batch_device` (the DC operating-point path).
+    """
+
+    m: np.ndarray                # (B, nz, nz) float64, host or device
+    c: np.ndarray                # (B, nz), where m is
     pattern: StampPattern
     amp_active: np.ndarray       # (B, n_amp_slots) bool — real amps only
     amp_rail: float
@@ -691,10 +698,12 @@ def assemble_batch(
 #     [0] diagonal | [1 .. C] cell couplings | [1+C ..] branch stamps
 #
 # with C the pattern's max cell terminals per node (1 for the proposed
-# design) and the branch slots assigned by an in-row cumulative count
-# (vectorized argsort/searchsorted, vmapped over the batch).  Only the
-# branch slots are data-dependent; everything else is static per
-# pattern, so the amp-row block is built once host-side and broadcast.
+# design) and the branch slots assigned by an in-row cumulative count,
+# computed on the host with the ELL width (a device argsort of the
+# branch list costs tens of seconds of TPU compile).  Only the branch
+# slots are data-dependent; everything else is static per pattern, so
+# the amp-row block is built once host-side and broadcast.  Index
+# arrays go to the device as int32.
 
 
 @dataclasses.dataclass
@@ -894,35 +903,47 @@ def _amp_rows_static(
 _AMP_ROW_WIDTH = 4
 
 
-def _ell_width(pat: StampPattern, vals: _BatchValues, c_max: int) -> int:
-    """Bounded ELL row degree: 1 diag + C cell couplings + max branch
-    degree across the batch, floored by the static amp-row width."""
-    n = pat.n_nodes
-    deg = np.zeros((vals.br_i.shape[0], n), dtype=np.int64)
-    valid = np.arange(vals.br_i.shape[1])[None, :] < vals.n_br[:, None]
-    bidx = np.arange(vals.br_i.shape[0])[:, None]
-    np.add.at(deg, (bidx, vals.br_i), valid.astype(np.int64))
-    np.add.at(deg, (bidx, vals.br_j), valid.astype(np.int64))
-    max_deg = int(deg.max()) if deg.size else 0
-    return max(1 + c_max + max_deg, _AMP_ROW_WIDTH)
+def _branch_slots(
+    nz: int, br_i: np.ndarray, br_j: np.ndarray, n_br: np.ndarray,
+    c_max: int,
+) -> tuple[np.ndarray, int]:
+    """Each branch stamp's ELL slot, and the bounded ELL row degree.
+
+    The stamps are ``[(br_i, br_j) | (br_j, br_i)]`` per system; a
+    stamp's slot is ``1 + C`` plus its count among earlier stamps of the
+    same row.  Invalid (padding) stamps land in row ``nz``, the
+    scatter's write-off row.  The width is 1 diag + C cell couplings +
+    the largest branch degree, floored by the static amp-row width.
+    """
+    b_count, nbr = br_i.shape
+    valid = np.arange(nbr)[None, :] < n_br[:, None]
+    rows = np.where(
+        np.concatenate([valid, valid], axis=1),
+        np.concatenate([br_i, br_j], axis=1),
+        nz,
+    )
+    # one cumulative count over the batch: rows offset per system
+    offset = np.arange(b_count, dtype=np.int64)[:, None] * (nz + 1)
+    count = _cumcount_np((rows + offset).reshape(-1)).reshape(rows.shape)
+    degree = np.where(rows < nz, count + 1, 0)
+    max_deg = int(degree.max()) if degree.size else 0
+    return 1 + c_max + count, max(1 + c_max + max_deg, _AMP_ROW_WIDTH)
 
 
-@functools.partial(
-    jax.jit, static_argnames=("n", "nz", "k", "c_start")
-)
+@functools.partial(jax.jit, static_argnames=("n", "nz", "k"))
 def _ell_assemble_jit(
     pair_i, pair_j, gcell_i,
     cell_rows, cell_cols, cell_slot,
     amp_idx, amp_w, amp_int_index,
-    br_i, br_j, br_g, n_br,
+    br_i, br_j, br_g, n_br, br_slot,
     pair_w, gcell_w, pair_active, g_active,
     ground_g, supply_g, s_cur, elem, v_os_slots,
     c_node, c_switch, c_in, w_u,
-    *, n: int, nz: int, k: int, c_start: int,
+    *, n: int, nz: int, k: int,
 ):
     """Device-side ELL scatter assembly (see module layout comment)."""
     b_count, nbr = br_i.shape
-    bidx = jnp.arange(b_count)[:, None]
+    bidx = jnp.arange(b_count, dtype=jnp.int32)[:, None]
     f64 = jnp.float64
 
     # ---- node capacitance (identical physics to the dense path) ----
@@ -972,15 +993,7 @@ def _ell_assemble_jit(
         )
         valid2 = jnp.concatenate([valid, valid], axis=1)
         r2 = jnp.where(valid2, r2, nz)
-
-        def cumcount(r):
-            s = r.shape[0]
-            order = jnp.argsort(r)                       # stable in jax
-            rs = r[order]
-            pos = jnp.arange(s) - jnp.searchsorted(rs, rs, side="left")
-            return jnp.zeros(s, pos.dtype).at[order].set(pos)
-
-        slot2 = jnp.minimum(c_start + jax.vmap(cumcount)(r2), k - 1)
+        slot2 = jnp.minimum(br_slot, k - 1)
         ell_w = ell_w.at[bidx, r2, slot2].add(jnp.where(valid2, v2, 0.0))
         ell_i = ell_i.at[bidx, r2, slot2].add(
             jnp.where(valid2, c2, 0).astype(jnp.int32)
@@ -995,6 +1008,65 @@ def _ell_assemble_jit(
         c_vec = c_vec.at[:, amp_int_index].add(w_u * v_os_slots)
 
     return ell_i[:, :nz], ell_w[:, :nz], c_vec
+
+
+def _ell_scatter(
+    nets: list[Netlist],
+    opamp: OpAmpSpec,
+    *,
+    v_os,
+    buffers: bool,
+    pattern: StampPattern | None,
+    device=None,
+):
+    """Gather the stamp values on the host, put them on ``device`` (the
+    default device if None) and dispatch the ELL scatter there.
+
+    Returns ``(pattern, values, indices, weights, c)`` with the last
+    three device arrays, not yet computed.  The branch arrays are
+    padded to a power of two and the ELL width rounded up to a multiple
+    of 8 (the extra branch entries are masked by ``n_br``, the extra
+    slots stay ``(0, 0.0)``), so the programs' shapes are the batch,
+    the pattern and these two size classes: a batch whose largest row
+    degree lands in another multiple of 8 compiles its programs once.
+    """
+    pat = pattern_union(nets, opamp, buffers=buffers) if pattern is None else pattern
+    params = _check_batch_params(nets)
+    vals = _gather_batch_values(nets, pat, v_os)
+
+    cell_rows, cell_cols, cell_slot, c_max = _node_cell_layout(pat)
+    nbr = vals.br_i.shape[1]
+    pad = (1 << (nbr - 1).bit_length()) - nbr if nbr else 0
+    br_i, br_j, br_g = (
+        np.pad(a, ((0, 0), (0, pad)))
+        for a in (vals.br_i, vals.br_j, vals.br_g)
+    )
+    br_slot, k = _branch_slots(pat.n_states, br_i, br_j, vals.n_br, c_max)
+    k = -(-k // 8) * 8
+    amp_idx, amp_w = _amp_rows_static(pat, opamp, buffers, k)
+
+    def i32(a):
+        return np.asarray(a, dtype=np.int32)
+
+    with span("core.transfer"):
+        arrays = jax.device_put(
+            (
+                i32(pat.pair_i), i32(pat.pair_j), i32(pat.gcell_i),
+                i32(cell_rows), cell_cols, i32(cell_slot),
+                amp_idx, amp_w, i32(pat.amp_int_index),
+                i32(br_i), i32(br_j), br_g, i32(vals.n_br), i32(br_slot),
+                vals.pair_w, vals.gcell_w, vals.pair_active, vals.g_active,
+                vals.ground_g, vals.supply_g, vals.s_cur, vals.elem,
+                vals.v_os_slots,
+            ),
+            device,
+        )
+    indices, weights, c_vec = _ell_assemble_jit(
+        *arrays,
+        params.c_node, params.c_switch, opamp.c_in, opamp.omega_u,
+        n=pat.n_nodes, nz=pat.n_states, k=k,
+    )
+    return pat, vals, indices, weights, c_vec
 
 
 def assemble_batch_ell(
@@ -1012,31 +1084,8 @@ def assemble_batch_ell(
     stamp-slot ELL form — host work and memory stay O(B * components)
     and nothing of size ``(B, nz, nz)`` is ever materialized.
     """
-    pat = pattern_union(nets, opamp, buffers=buffers) if pattern is None else pattern
-    _check_batch_params(nets)
-    vals = _gather_batch_values(nets, pat, v_os)
-
-    cell_rows, cell_cols, cell_slot, c_max = _node_cell_layout(pat)
-    k = _ell_width(pat, vals, c_max)
-    amp_idx, amp_w = _amp_rows_static(pat, opamp, buffers, k)
-
-    indices, weights, c_vec = _ell_assemble_jit(
-        jnp.asarray(pat.pair_i), jnp.asarray(pat.pair_j),
-        jnp.asarray(pat.gcell_i),
-        jnp.asarray(cell_rows), jnp.asarray(cell_cols),
-        jnp.asarray(cell_slot),
-        jnp.asarray(amp_idx), jnp.asarray(amp_w),
-        jnp.asarray(pat.amp_int_index),
-        jnp.asarray(vals.br_i), jnp.asarray(vals.br_j),
-        jnp.asarray(vals.br_g), jnp.asarray(vals.n_br),
-        jnp.asarray(vals.pair_w), jnp.asarray(vals.gcell_w),
-        jnp.asarray(vals.pair_active), jnp.asarray(vals.g_active),
-        jnp.asarray(vals.ground_g), jnp.asarray(vals.supply_g),
-        jnp.asarray(vals.s_cur), jnp.asarray(vals.elem),
-        jnp.asarray(vals.v_os_slots),
-        nets[0].params.c_node, nets[0].params.c_switch,
-        opamp.c_in, opamp.omega_u,
-        n=pat.n_nodes, nz=pat.n_states, k=k, c_start=1 + c_max,
+    pat, vals, indices, weights, c_vec = _ell_scatter(
+        nets, opamp, v_os=v_os, buffers=buffers, pattern=pattern
     )
     return EllBatchedStateSpace(
         indices=indices,
@@ -1067,9 +1116,104 @@ def assemble_batch_ell(
 DC_REFINE_TOL = 1e-15
 DC_REFINE_MAX_ITERS = 10
 
-# host-side repairs made by dc_solve_batch_finalize: systems whose device
-# solve came back non-finite and were re-solved with numpy in f64
-DC_STATS = {"host_resolves": 0}
+# host_resolves: systems whose device DC solve came back non-finite and
+# were re-solved with numpy in f64 by dc_solve_batch_finalize;
+# device_assembled: DC micro-batches whose operator assemble_batch_device
+# built on the device
+DC_STATS = {"host_resolves": 0, "device_assembled": 0}
+
+
+# ELL slots _ell_densify selects per pass over the dense output: the
+# pass is one fused select chain, so its length bounds the compile
+_DENSIFY_GROUP = 8
+
+
+@jax.jit
+def _ell_densify(indices: jnp.ndarray, weights: jnp.ndarray) -> jnp.ndarray:
+    """The dense ``(B, nz, nz)`` operator of a batched ELL one, on the
+    device.
+
+    A TPU scatter writes one element at a time, so the rows are built
+    with selects: each row's slots are sorted by column and every run of
+    one column summed (a segmented scan over the slots; padding slots,
+    ``(0, 0.0)``, join column 0's run), then each run's total is
+    selected into its column, ``_DENSIFY_GROUP`` slots per pass.  An
+    entry is a copy of one total: the scatter-add's result, duplicate
+    stamps summed in another order.
+    """
+    b, nz, k = indices.shape
+    cols, w = jax.lax.sort((indices, weights), dimension=2, num_keys=1)
+    new_col = cols[..., 1:] != cols[..., :-1]
+    edge = jnp.ones((b, nz, 1), bool)
+    # segmented inclusive scan: w[s] becomes its run's sum up to slot s
+    head = jnp.concatenate([edge, new_col], axis=2)
+    step = 1
+    while step < k:
+        shift = ((0, 0), (0, 0), (step, 0))
+        w = jnp.where(head, w, w + jnp.pad(w[..., :-step], shift))
+        head = head | jnp.pad(head[..., :-step], shift, constant_values=True)
+        step *= 2
+    # only a run's last slot is selected; the others match no column
+    last = jnp.concatenate([new_col, edge], axis=2)
+    pad = ((0, 0), (0, 0), (0, -k % _DENSIFY_GROUP))
+    cols = jnp.pad(jnp.where(last, cols, -1), pad, constant_values=-1)
+    w = jnp.pad(w, pad)
+    col_ids = jnp.arange(nz, dtype=cols.dtype)
+
+    def select_group(g, dense):
+        c = jax.lax.dynamic_slice_in_dim(cols, g * _DENSIFY_GROUP,
+                                         _DENSIFY_GROUP, axis=2)
+        v = jax.lax.dynamic_slice_in_dim(w, g * _DENSIFY_GROUP,
+                                         _DENSIFY_GROUP, axis=2)
+        for s in range(_DENSIFY_GROUP):
+            dense = jnp.where(c[:, :, s, None] == col_ids, v[:, :, s, None],
+                              dense)
+        return dense
+
+    return jax.lax.fori_loop(
+        0, cols.shape[2] // _DENSIFY_GROUP, select_group,
+        jnp.zeros((b, nz, nz), weights.dtype),
+    )
+
+
+@span("core.assemble")
+def assemble_batch_device(
+    nets: list[Netlist],
+    opamp: OpAmpSpec = AD712,
+    *,
+    v_os: list[np.ndarray | float | None] | None = None,
+    buffers: bool = True,
+    pattern: StampPattern | None = None,
+    device=None,
+) -> BatchedStateSpace:
+    """Dense state-space assembly on the device, for the DC solve.
+
+    Same physics and arguments as :func:`assemble_batch`.  The host
+    gathers the ``O(B * components)`` stamp values and sends them to
+    ``device`` (the default device if None); there the ELL scatter of
+    :func:`assemble_batch_ell` and :func:`_ell_densify` build ``m`` and
+    ``c`` as device arrays, so nothing of size ``(B, nz, nz)`` exists on
+    the host or crosses to the device.  Both programs are dispatched
+    and not waited on.  Their compile keys are the pattern, the batch
+    size, the power-of-two class of the branch count and the ELL width
+    rounded up to a multiple of 8 (the largest row degree): another
+    operator of the same bucket reuses both executables unless it
+    changes one of the two classes.  Counted in
+    ``DC_STATS["device_assembled"]``.
+    """
+    pat, vals, indices, weights, c_vec = _ell_scatter(
+        nets, opamp, v_os=v_os, buffers=buffers, pattern=pattern,
+        device=device,
+    )
+    DC_STATS["device_assembled"] += 1
+    return BatchedStateSpace(
+        m=_ell_densify(indices, weights),
+        c=c_vec,
+        pattern=pat,
+        amp_active=vals.amp_active,
+        amp_rail=opamp.rail_v,
+        slew=opamp.slew_v_per_s,
+    )
 
 
 def _dc_solve_refined(m: jnp.ndarray, c: jnp.ndarray) -> jnp.ndarray:
@@ -1147,8 +1291,12 @@ def dc_solve_batch_submit(
     applies the singular-support fallback; :func:`dc_solve_batch` is
     exactly submit + finalize.
 
+    ``bss.m`` / ``bss.c`` may be host arrays (:func:`assemble_batch`),
+    copied over here, or device arrays (:func:`assemble_batch_device`,
+    the operating-point path), which stay where they are: a
+    ``device_put`` to the device an array already lives on is a no-op.
     ``device`` places the whole batch on one device (per-device solve
-    streams, donated operand buffers where the platform supports
+    streams, a donated copy of ``c`` where the platform supports
     aliasing); ``mesh`` instead shards the batch axis over a 1-d solver
     mesh (:func:`repro.distributed.sharding.solver_mesh`).  The two are
     mutually exclusive.
@@ -1158,7 +1306,9 @@ def dc_solve_batch_submit(
     with span("core.transfer"):
         if device is not None:
             m = jax.device_put(bss.m, device)
-            c = jax.device_put(bss.c, device)
+            # always a fresh buffer: the solve donates it, and a device
+            # bss.c must outlive it for the host repair
+            c = jax.device_put(bss.c, device, may_alias=False)
         else:
             m = jnp.asarray(bss.m)
             c = jnp.asarray(bss.c)
@@ -1176,7 +1326,11 @@ def dc_solve_batch_submit(
 def dc_solve_batch_finalize(
     z_dev: jnp.ndarray, bss: BatchedStateSpace
 ) -> np.ndarray:
-    """Block on an in-flight DC solve and apply the singular fallback."""
+    """Block on an in-flight DC solve and apply the singular fallback.
+
+    Only a non-finite row's operator is read back (``np.asarray(m[b])``)
+    when ``bss`` lives on the device; a finite batch fetches ``z`` alone.
+    """
     z = np.asarray(z_dev)
     bad = ~np.all(np.isfinite(z), axis=1)
     if np.any(bad):
@@ -1186,8 +1340,9 @@ def dc_solve_batch_finalize(
         z = np.array(z, dtype=np.float64)
         eye = np.eye(bss.n_states)
         for b in np.nonzero(bad)[0]:
-            eps = 1e-12 * np.abs(bss.m[b]).max()
-            z[b] = np.linalg.solve(bss.m[b] - eps * eye, -bss.c[b])
+            m_b = np.asarray(bss.m[b])
+            eps = 1e-12 * np.abs(m_b).max()
+            z[b] = np.linalg.solve(m_b - eps * eye, -np.asarray(bss.c[b]))
     return z
 
 

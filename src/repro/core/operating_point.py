@@ -202,19 +202,30 @@ class PendingBatchOperatingPoint:
     """An in-flight batched DC solve: host metadata + the device future.
 
     Produced by :func:`operating_point_batch_submit` after the host-side
-    work (error model, batched assembly) is done and the vmapped solve
-    has been *dispatched*; under JAX async dispatch the device computes
-    while the caller builds its next micro-batch.  :meth:`wait` blocks,
+    work (error model, stamp values) is done and the device assembly
+    and the vmapped solve have been *dispatched*; under JAX async
+    dispatch the device computes while the caller builds its next
+    micro-batch.  :meth:`wait` blocks,
     materializes and unpacks — ``operating_point_batch`` is exactly
-    submit + wait, so the two paths cannot drift.
+    submit + wait, so the two paths cannot drift.  It is idempotent,
+    and once it has run the handle holds no device array: the
+    ``(B, nz, nz)`` operator lives on the device from submit until the
+    harvest and no longer.
     """
 
-    _bss: "engine.BatchedStateSpace"
+    _bss: "engine.BatchedStateSpace | None"
     _z_dev: object
     _x_ref: np.ndarray | None
     _batch: int
+    _done: BatchOperatingPoint | None = None
 
     def wait(self) -> BatchOperatingPoint:
+        if self._done is None:
+            self._done = self._harvest()
+            self._bss = self._z_dev = None
+        return self._done
+
+    def _harvest(self) -> BatchOperatingPoint:
         bss = self._bss
         z = engine.dc_solve_batch_finalize(self._z_dev, bss)
         nn = bss.n_nodes
@@ -266,11 +277,15 @@ def operating_point_batch_submit(
 ) -> PendingBatchOperatingPoint:
     """Host phase of the batched DC analysis + async device dispatch.
 
-    Applies the per-system error model and assembles the batch on the
-    shared stamp pattern (host-side numpy), then dispatches the batched
-    fp64-refined DC solve — on one ``device`` (per-device solve streams, see
-    :func:`repro.core.engine.dc_solve_batch_submit`) or sharded over
-    ``mesh`` — and returns without blocking.
+    Applies the per-system error model, then assembles the batch on the
+    shared stamp pattern on the device
+    (:func:`repro.core.engine.assemble_batch_device`: the host sends the
+    stamp values, the dense operator is built where it is solved) and
+    dispatches the batched fp64-refined DC solve — on one ``device``
+    (per-device solve streams, see
+    :func:`repro.core.engine.dc_solve_batch_submit`), or assembled on
+    the default device and sharded over ``mesh`` — and returns without
+    blocking.  No ``(B, nz, nz)`` array exists on the host.
     """
     spec = opamp
     if not nonideal.use_finite_gain:
@@ -280,7 +295,9 @@ def operating_point_batch_submit(
         draw_offsets(spec, net.n_amps, nonideal.offset_mode, nonideal.seed)
         for net in nets_ni
     ]
-    bss = engine.assemble_batch(nets_ni, spec, v_os=v_os, pattern=pattern)
+    bss = engine.assemble_batch_device(
+        nets_ni, spec, v_os=v_os, pattern=pattern, device=device
+    )
     z_dev = engine.dc_solve_batch_submit(bss, mesh=mesh, device=device)
     return PendingBatchOperatingPoint(
         _bss=bss, _z_dev=z_dev, _x_ref=x_ref, _batch=len(nets)

@@ -85,8 +85,10 @@ span that is innermost at a moment names what the host was doing:
   netlists: ``core.transform``, the Sec-IV transform and its
   ``net_build``-labelled sync, then numpy extraction),
   ``core.pattern`` (the cached stamp pattern's cover check or
-  derivation), ``core.assemble`` (the dense operator) and
-  ``core.transfer`` (the host-to-device copy) before the async solve;
+  derivation), ``core.assemble`` (the DC operator, built on the
+  device: the host gathers the stamp values, sends them under a nested
+  ``core.transfer`` and dispatches the assembly) and ``core.transfer``
+  (the solve's operands, already on the device) before the async solve;
 * ``serve.harvest`` (``harvest``) — the block on a micro-batch's DC
   phase;
 * ``serve.finish`` (``finish``) — a deferred finish phase:
@@ -567,6 +569,7 @@ class SolveService:
             "requeued_on_quarantine": 0,
             "errors": {k: 0 for k in ERROR_KINDS},
             "neg_cells": 0,
+            "device_assembled": 0,
             "cross_branches": 0,
             "settle_steps_swept": 0,
         }
@@ -1126,6 +1129,7 @@ class SolveService:
         popped = self.queue.pop_all()
         if not popped:
             return {}
+        assembled_before = engine.DC_STATS["device_assembled"]
         # every span opened below, in core/ too, lands on this service
         with self._spans.installed(), span("serve.drain"):
             out: dict[int, SolveResult | SolveError] = {}
@@ -1220,6 +1224,10 @@ class SolveService:
                 # next drain, nothing is silently discarded
                 self.queue.requeue(popped)
                 raise
+            finally:
+                self._counters["device_assembled"] += (
+                    engine.DC_STATS["device_assembled"] - assembled_before
+                )
         return out
 
     # ----------------------------------------------------------- sessions
@@ -1260,9 +1268,13 @@ class SolveService:
         each dispatched ticket's wait from ``submit`` to the start of
         its first ``serve.dispatch``.  ``neg_cells`` /
         ``cross_branches`` / ``settle_steps_swept`` are the circuit
-        counters of the module docstring.  ``pattern_derivations`` counts
-        ``pattern_union`` calls per bucket (1 proves the cache served
-        every later micro-batch on every stream).
+        counters of the module docstring.  ``device_assembled`` counts
+        the analog DC micro-batches whose operator was built on the
+        device (:func:`repro.core.engine.assemble_batch_device`) during
+        this service's drains: every analog DC dispatch, the inner
+        passes of graded refinement included.  ``pattern_derivations``
+        counts ``pattern_union`` calls per bucket (1 proves the cache
+        served every later micro-batch on every stream).
 
         The fault-tolerance story rides along: ``retries`` /
         ``bisections`` (non-terminal recovery work), ``shed`` /
@@ -1320,6 +1332,7 @@ class SolveService:
             "queue_wait_s": self._queue_wait_s,
             "spans": spans.snapshot(),
             "neg_cells": c["neg_cells"],
+            "device_assembled": c["device_assembled"],
             "cross_branches": c["cross_branches"],
             "settle_steps_swept": c["settle_steps_swept"],
             "devices": len(self.devices),

@@ -124,6 +124,213 @@ def test_dc_solve_counts_host_resolves_of_singular_systems():
     assert np.max(rel) <= 1e-12
 
 
+def _dc_nets(seed, n, count, *, builder=build_proposed, density=1.0):
+    rng = np.random.default_rng(seed)
+    nets = []
+    for _ in range(count):
+        a = random_spd(rng, n, density=density)
+        _x, b = random_rhs_from_solution(rng, a)
+        nets.append(builder(a, b))
+    return nets
+
+
+def _rel_rows(z, ref):
+    return np.max(np.abs(z - ref), axis=1) / np.max(np.abs(ref), axis=1)
+
+
+@pytest.mark.parametrize(
+    "builder, v_os, buffers",
+    [
+        (build_proposed, False, True),
+        (build_preliminary, False, True),
+        (build_proposed, True, True),
+        (build_preliminary, True, True),
+        (build_proposed, False, False),
+    ],
+    ids=["proposed", "preliminary", "proposed-v_os", "preliminary-v_os",
+         "proposed-no-buffers"],
+)
+def test_device_assembly_matches_host_assembly(builder, v_os, buffers):
+    """The DC operator built on the device is the host dense operator to
+    f64 round-off, and the DC point solved from it matches the host-
+    assembled solve; operating_point_batch (which takes the device path)
+    matches the host-assembled dc_solve_batch to 1e-12 relative."""
+    import jax
+
+    from repro.core.operating_point import (
+        NonIdealities, apply_nonidealities, draw_offsets,
+        operating_point_batch,
+    )
+    from repro.core.specs import AD712
+
+    nets = _dc_nets(43, 12, 4, builder=builder)
+    rng = np.random.default_rng(5)
+    offs = (
+        [rng.uniform(-1e-3, 1e-3, size=net.n_amps) for net in nets]
+        if v_os else None
+    )
+    host = engine.assemble_batch(nets, v_os=offs, buffers=buffers)
+    dev = engine.assemble_batch_device(nets, v_os=offs, buffers=buffers)
+    assert isinstance(dev.m, jax.Array) and isinstance(dev.c, jax.Array)
+    scale = np.abs(host.m).max()
+    np.testing.assert_allclose(np.asarray(dev.m), host.m, rtol=0.0,
+                               atol=1e-12 * scale)
+    np.testing.assert_allclose(np.asarray(dev.c), host.c, rtol=1e-12)
+    assert np.array_equal(dev.amp_active, host.amp_active)
+    assert _rel_rows(engine.dc_solve_batch(dev),
+                     engine.dc_solve_batch(host)).max() <= 1e-12
+
+    if buffers:
+        # the operating-point path: its error model, then the device
+        # assembly, against the same error model assembled on the host
+        ni = DEFAULT_NONIDEAL if v_os else NonIdealities(offset_mode="none")
+        op = operating_point_batch(nets, nonideal=ni)
+        nets_ni = [apply_nonidealities(net, ni) for net in nets]
+        draws = [draw_offsets(AD712, net.n_amps, ni.offset_mode, ni.seed)
+                 for net in nets_ni]
+        z = engine.dc_solve_batch(
+            engine.assemble_batch(nets_ni, AD712, v_os=draws)
+        )
+        assert _rel_rows(op.x, z[:, : op.x.shape[1]]).max() <= 1e-12
+
+
+def test_device_assembly_sums_duplicate_stamps():
+    """Two branches between one node pair stamp one operator entry: the
+    device densify sums them as the host scatter-add does."""
+    import dataclasses
+
+    nets = [
+        dataclasses.replace(
+            net,
+            branch_i=np.concatenate([net.branch_i, net.branch_i[:5]]),
+            branch_j=np.concatenate([net.branch_j, net.branch_j[:5]]),
+            branch_g=np.concatenate([net.branch_g, net.branch_g[:5]]),
+        )
+        for net in _dc_nets(53, 10, 3)
+    ]
+    host = engine.assemble_batch(nets)
+    dev = engine.assemble_batch_device(nets)
+    np.testing.assert_allclose(np.asarray(dev.m), host.m, rtol=0.0,
+                               atol=1e-12 * np.abs(host.m).max())
+
+
+def test_operating_point_batch_never_materializes_dense(monkeypatch):
+    """Shape spy: the DC operating-point path builds no (B, nz, nz)
+    host array and never calls the host dense assembly; one
+    device_assembled count per micro-batch."""
+    from repro.core.operating_point import (
+        IDEAL, operating_point_batch_submit,
+    )
+
+    nets = _dc_nets(47, 12, 3)
+    pat = engine.pattern_union(nets)
+    nz = pat.n_states
+    forbidden = []
+
+    def spy(fn):
+        def wrapped(shape, *a, **kw):
+            s = tuple(shape) if isinstance(shape, (tuple, list)) else (shape,)
+            if len(s) == 3 and s[1] >= nz and s[2] >= nz:
+                forbidden.append(s)
+            return fn(shape, *a, **kw)
+        return wrapped
+
+    for name in ("zeros", "empty", "full"):
+        monkeypatch.setattr(np, name, spy(getattr(np, name)))
+    monkeypatch.setattr(
+        engine, "assemble_batch",
+        lambda *a, **kw: (_ for _ in ()).throw(
+            AssertionError("host dense assembly on the DC path")),
+    )
+
+    before = dict(engine.DC_STATS)
+    ops = [
+        operating_point_batch_submit(nets, nonideal=IDEAL, pattern=pat)
+        for _ in range(2)
+    ]
+    xs = [op.wait().x for op in ops]
+    assert forbidden == []
+    assert engine.DC_STATS["device_assembled"] == before["device_assembled"] + 2
+    assert engine.DC_STATS["host_resolves"] == before["host_resolves"]
+    np.testing.assert_array_equal(xs[0], xs[1])
+    assert np.all(np.isfinite(xs[0]))
+
+
+def _largest_row_width(nets, pat):
+    """The ELL width before its rounding: 1 + C + the largest row degree."""
+    vals = engine._gather_batch_values(nets, pat, None)
+    c_max = engine._node_cell_layout(pat)[3]
+    return engine._branch_slots(
+        pat.n_states, vals.br_i, vals.br_j, vals.n_br, c_max
+    )[1]
+
+
+def test_device_assembly_reuses_executables_and_repairs_on_host():
+    """A second micro-batch with another operator of the same n (fewer
+    branches and a smaller largest row degree, in the same power-of-two
+    and multiple-of-8 classes) compiles nothing; a singular system in a
+    device-assembled batch is re-solved on the host and counted, and its
+    batch-mates keep their device result."""
+    import dataclasses
+
+    from repro.analysis.runtime import CompileWatch
+    from repro.core.operating_point import IDEAL, operating_point_batch
+
+    nets1 = _dc_nets(3, 12, 4, density=0.9)
+    nets2 = _dc_nets(3, 12, 4, density=0.4)
+    n_br = [max(net.n_branches for net in nets) for nets in (nets1, nets2)]
+    assert n_br[0] != n_br[1]
+    pat = engine.pattern_union(nets1 + nets2)
+    widths = [_largest_row_width(nets, pat) for nets in (nets1, nets2)]
+    assert widths[0] != widths[1]
+    operating_point_batch(nets1, nonideal=IDEAL, pattern=pat)   # warm
+    with CompileWatch() as watch:
+        op2 = operating_point_batch(nets2, nonideal=IDEAL, pattern=pat)
+    assert watch.count == 0, watch.names
+    assert np.all(np.isfinite(op2.x))
+
+    dev = engine.assemble_batch_device(nets1, pattern=pat)
+    singular = dataclasses.replace(dev, m=dev.m.at[1, :, 0].set(0.0))
+    resolves = engine.DC_STATS["host_resolves"]
+    z = engine.dc_solve_batch(singular)
+    assert engine.DC_STATS["host_resolves"] == resolves + 1
+    assert np.all(np.isfinite(z))
+    host = engine.assemble_batch(nets1, pattern=pat)
+    keep = [0, 2, 3]
+    ref = np.linalg.solve(host.m[keep], -host.c[keep, :, None])[..., 0]
+    assert _rel_rows(z[keep], ref).max() <= 1e-12
+
+
+def test_settle_drain_frees_each_dc_operator_at_its_harvest(monkeypatch):
+    """A settle drain of three micro-batches: the deferred finishes run
+    once every DC phase is harvested, and by then no (B, nz, nz) device
+    operator is live (each handle lets its operator go at the harvest)."""
+    import jax
+
+    from repro.serving.solve_service import SolveService
+
+    rng = np.random.default_rng(19)
+    a = random_spd(rng, 8)
+    live = []
+    sweep = engine.transient_batch
+
+    def spy(nets, *args, **kw):
+        live.append([
+            x.shape for x in jax.live_arrays()
+            if x.ndim == 3 and x.shape[1] == x.shape[2] > 8
+        ])
+        return sweep(nets, *args, **kw)
+
+    monkeypatch.setattr(engine, "transient_batch", spy)
+    svc = SolveService(batch_slots=2)
+    for b in rng.uniform(0.5, 1.0, size=(5, 8)) @ a:
+        svc.submit(a, b, compute_settling=True, settle_method="euler")
+    out = svc.drain()
+    assert len(out) == 5 and all(r.stable for r in out.values())
+    assert svc.stats["device_assembled"] == 3
+    assert live == [[], [], []]
+
+
 def test_pattern_cache_reused_across_batches():
     """Proposed-design patterns depend only on (n, design)."""
     a1, x1, b1 = _batch(17, 8, 3)

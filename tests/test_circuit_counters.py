@@ -102,3 +102,32 @@ def test_settle_steps_swept_is_each_micro_batch_slowest_ticket(settled):
     steps = st["ticket_steps"]
     want = sum(max(steps[k: k + SLOTS]) for k in range(0, len(steps), SLOTS))
     assert st["settle_steps_swept"] == want
+
+
+@pytest.mark.parametrize("refine", [None, True], ids=["plain", "refine"])
+def test_device_assembled_counts_every_analog_dc_micro_batch(refine):
+    """Every analog DC micro-batch builds its operator on the device:
+    one count per dispatch, and one per inner pass of graded refinement
+    (each builds its netlists once, as a dispatch does).  The op-amps'
+    finite gain leaves the raw solve short of the refinement tol."""
+    from repro.core.operating_point import NonIdealities
+
+    a = _operator("poisson")
+    rng = np.random.default_rng(13)
+    svc = SolveService(batch_slots=SLOTS, refine=refine)
+    for b in rng.uniform(0.5, 1.0, size=(3, a.shape[0])) @ a:
+        svc.submit(a, b, nonideal=NonIdealities(offset_mode="none"))
+    svc.drain()
+    st = svc.stats
+    assert st["spans"]["serve.dispatch"]["count"] == 2
+    assert st["device_assembled"] == st["spans"]["core.build_nets"]["count"]
+    if refine:
+        assert st["refine_iters_total"] > 0
+        assert st["device_assembled"] > 2
+    else:
+        assert st["device_assembled"] == 2
+
+
+def test_settle_cell_assembles_its_dc_operator_on_the_device(settled):
+    _, _, _, st = settled
+    assert st["device_assembled"] == st["spans"]["serve.dispatch"]["count"]

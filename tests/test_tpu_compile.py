@@ -134,6 +134,19 @@ def test_dc_solve_compiles_without_f64_lu(one_chip):
     ).compile()
 
 
+def test_dc_densify_compiles_without_scatter(one_chip):
+    """The DC operator's densify at the n=192 analog_2n bucket's ELL
+    width (dense requests: 193 slots, bucketed to 200) builds the rows
+    with selects: a TPU scatter writes one element at a time."""
+    from repro.core import engine
+
+    slots = (BATCH, DC_NZ, 200)
+    text = engine._ell_densify.lower(
+        _spec(one_chip, slots, jnp.int32), _spec(one_chip, slots, jnp.float64)
+    ).compile().as_text()
+    assert " scatter(" not in text
+
+
 def test_cholesky_baseline_compiles(one_chip):
     from repro.core.baselines import cholesky_solve_batch
 
