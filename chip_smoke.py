@@ -5,10 +5,10 @@ points a user calls, and checks every answer against a plain float64
 reference computed on the host:
 
 (a) device check — the first JAX device must be a TPU, else exit 2;
-(b) service stream — the 36-ticket mix of
-    ``benchmarks.solve_service.build_stream(seed, repeat=4)``
-    (n in {16, 24, 64, 192}; analog_2n, analog_n, cholesky) through
-    ``SolveService(batch_slots=8)``: a warm drain, then a timed drain.
+(b) service stream — the 36-ticket stream of :func:`build_stream`, its
+    nine-entry ``MIX`` four times over (n in {16, 24, 64, 192};
+    analog_2n, analog_n, cholesky) through ``SolveService(batch_slots=8)``:
+    a warm drain, then a timed drain.
     Every DC operating point the device computes must match host
     ``np.linalg.solve(M, -c)`` to 1e-9 relative, and every answer,
     analog (ideal hardware) or Cholesky, host ``np.linalg.solve(a, b)``
@@ -16,8 +16,8 @@ reference computed on the host:
     with ``refine=True`` (warm + timed drain) must bring every ticket
     to fp64 relative residual <= 1e-10, analog tickets via the
     ``analog`` or ``refined`` precision path;
-(c) ELL settle sweep — B=8 sparse systems at n=2048 (row degree 16, as
-    in ``benchmarks.tpu_complexity``) through ``engine.assemble_batch_ell``
+(c) ELL settle sweep — B=8 sparse systems at n=2048 (expected row
+    degree 16, :func:`sparse_systems`) through ``engine.assemble_batch_ell``
     and ``engine.euler_settle_batch``: every system must settle before
     ``max_steps`` through compiled Pallas kernels;
 (d) failure counts — SolveErrors, digital fallbacks, host DC re-solves,
@@ -61,6 +61,53 @@ SETTLE_N = 2048
 SETTLE_B = 8
 SETTLE_MAX_STEPS = 30_000
 SETTLE_CHECK_EVERY = 250
+SETTLE_ROW_DEGREE = 16
+# (n, method, kind) of one pass of the service stream; n = 24 is off the
+# padding grid and pads into the n = 32 bucket
+MIX = (
+    (16, "analog_2n", "spd"),
+    (16, "analog_2n", "sdd"),
+    (16, "analog_n", "spd"),
+    (16, "cholesky", "spd"),
+    (24, "analog_2n", "spd"),
+    (64, "analog_2n", "spd"),
+    (64, "cholesky", "spd"),
+    (192, "analog_2n", "spd"),
+    (192, "cholesky", "spd"),
+)
+
+
+def build_stream(seed: int, repeat: int) -> list[dict]:
+    """``MIX`` ``repeat`` times over, every system drawn from one
+    generator seeded with ``seed``: paper-protocol SPD (or SDD) A and
+    b = A x for x ~ U[-0.5, 0.5] V."""
+    from repro.data.spd import random_rhs_from_solution, random_sdd, random_spd
+
+    rng = np.random.default_rng(seed)
+    out = []
+    for _ in range(repeat):
+        for n, method, kind in MIX:
+            a = random_sdd(rng, n) if kind == "sdd" else random_spd(rng, n)
+            _x, b = random_rhs_from_solution(rng, a)
+            out.append({"a": a, "b": b, "method": method})
+    return out
+
+
+def sparse_systems(rng, n: int, count: int):
+    """``count`` proposed-design netlists of sparse paper-protocol SPD
+    systems at expected row degree ``SETTLE_ROW_DEGREE``, and their
+    solutions ``(count, n)``."""
+    from repro.core.network import build_proposed
+    from repro.data.spd import random_rhs_from_solution, random_spd
+
+    density = min(1.0, SETTLE_ROW_DEGREE / n)
+    nets, xs = [], []
+    for _ in range(count):
+        a = random_spd(rng, n, density=density)
+        x, b = random_rhs_from_solution(rng, a)
+        nets.append(build_proposed(a, b))
+        xs.append(x)
+    return nets, np.stack(xs)
 
 
 def report(**fields) -> None:
@@ -210,8 +257,6 @@ def check_stream(run: dict, stream: list[dict], *, refined: bool) -> dict:
 
 
 def phase_service() -> list[str]:
-    from benchmarks.solve_service import build_stream
-
     stream = build_stream(SEED, repeat=STREAM_REPEAT)
     failures = []
     for refined in (False, True):
@@ -223,13 +268,12 @@ def phase_service() -> list[str]:
 
 
 def phase_settle(allow_cpu: bool) -> list[str]:
-    from benchmarks.tpu_complexity import _sparse_systems
     from repro.analysis.runtime import CompileWatch
     from repro.core import engine
     from repro.kernels import ops
 
     rng = np.random.default_rng(SEED)
-    nets, x, _density = _sparse_systems(rng, SETTLE_N, SETTLE_B)
+    nets, x = sparse_systems(rng, SETTLE_N, SETTLE_B)
     launches0 = dict(ops.KERNEL_STATS)
     with CompileWatch() as watch:
         t0 = time.perf_counter()
@@ -267,8 +311,6 @@ def phase_settle(allow_cpu: bool) -> list[str]:
 
 def phase_streams(chips: int) -> list[str]:
     """The stream phase on ``chips`` device streams against one."""
-    from benchmarks.solve_service import build_stream
-
     stream = build_stream(SEED, repeat=STREAM_REPEAT)
     one = serve(stream, n_devices=1)
     many = serve(stream, n_devices=chips)
@@ -300,7 +342,7 @@ def main() -> int:
                     help="rehearsal only: run without a TPU")
     args = ap.parse_args()
 
-    from benchmarks.common import enable_compile_cache
+    from bench.harness import enable_compile_cache
 
     device = device_check(args.allow_cpu, args.chips)
     report(phase="device", compile_cache=enable_compile_cache(), **device)

@@ -1,8 +1,14 @@
-"""One benchmark per paper figure/table (Figs. 8-16, Tables I-II).
+"""The paper's figures and tables (Figs. 8-16, Tables I-II), one
+function each.
 
-Each function mirrors the paper's experimental protocol; EXPERIMENTS.md
-§Paper-claims records the comparison against the paper's reported
-numbers.  Default sizes are CPU-reduced; ``--full`` widens them.
+Each function mirrors the paper's experimental protocol and returns
+rows ``{"name": ..., metric: value, ...}``; ``main`` prints them as
+``name,metric,value`` CSV.  Default sizes are CPU-reduced; ``--full``
+widens them to the paper's sweeps (slow); ``--smoke`` runs the quick
+subset (Figs. 8 and 10, Tables I and II, the D-policy comparison).
+
+    PYTHONPATH=src python examples/paper_figs.py [--full] [--smoke]
+        [--only fig12,fig13]
 
 The sweeps run on the batched engine (:mod:`repro.core.engine`): each
 size/parameter class builds its netlists host-side, then errors come
@@ -13,15 +19,46 @@ instead of per-system Python loops.
 
 from __future__ import annotations
 
+import argparse
+
 import numpy as np
 
-from benchmarks.common import US, gen_systems, stats
 from repro.core import engine
 from repro.core.network import build_preliminary, build_proposed
 from repro.core.operating_point import NonIdealities, operating_point_batch
 from repro.core.specs import AD712, OPAMPS
-from repro.core.transient import lti_transient
 from repro.core.transient_nl import nonlinear_transient_batch
+
+US = 1e-6
+SMOKE = ("fig8", "fig10", "table1", "table2", "dpolicy")
+
+
+def gen_systems(seed: int, n: int, count: int, density: float = 1.0):
+    """Paper protocol systems: eigenvalues in [10, 1000] uS,
+    x ~ U[-0.5, 0.5], b = A x."""
+    from repro.data.spd import random_rhs_from_solution, random_spd
+
+    rng = np.random.default_rng(seed)
+    out = []
+    for _ in range(count):
+        a = random_spd(rng, n, density=density)
+        x, b = random_rhs_from_solution(rng, a)
+        out.append((a, x, b))
+    return out
+
+
+def stats(xs) -> dict:
+    """Median, 90th percentile, max and count of the finite entries."""
+    xs = np.asarray([x for x in xs if np.isfinite(x)], dtype=np.float64)
+    if xs.size == 0:
+        return {"median": float("nan"), "p90": float("nan"),
+                "max": float("nan"), "n": 0}
+    return {
+        "median": float(np.median(xs)),
+        "p90": float(np.percentile(xs, 90)),
+        "max": float(xs.max()),
+        "n": int(xs.size),
+    }
 
 
 MACRO = NonIdealities(offset_mode="none")          # SPICE-macro-equivalent
@@ -240,27 +277,6 @@ def table2_components(full: bool = False) -> list[dict]:
     return rows
 
 
-def tpu_complexity(full: bool = False) -> list[dict]:
-    from benchmarks.tpu_complexity import run as _run
-
-    return _run(full=full)
-
-
-ALL = {
-    "fig8": fig8_stability,
-    "fig9": fig9_preliminary,
-    "fig10": fig10_beta,
-    "fig12": fig12_complexity,
-    "fig13": fig13_fixed_conductance,
-    "fig14": fig14_density05,
-    "fig15": fig15_opamps,
-    "fig16": fig16_alpha,
-    "table1": table1_specs,
-    "table2": table2_components,
-    "tpu_complexity": tpu_complexity,
-}
-
-
 def d_policy_comparison(full: bool = False) -> list[dict]:
     """Sec. IV-A: the paper's D (Eq. 22) vs Gremban's support-tree
     transform (D = diag(A), K_s = 0).  The paper's point: Gremban's
@@ -287,4 +303,52 @@ def d_policy_comparison(full: bool = False) -> list[dict]:
     return rows
 
 
-ALL["dpolicy"] = d_policy_comparison
+ALL = {
+    "fig8": fig8_stability,
+    "fig9": fig9_preliminary,
+    "fig10": fig10_beta,
+    "fig12": fig12_complexity,
+    "fig13": fig13_fixed_conductance,
+    "fig14": fig14_density05,
+    "fig15": fig15_opamps,
+    "fig16": fig16_alpha,
+    "table1": table1_specs,
+    "table2": table2_components,
+    "dpolicy": d_policy_comparison,
+}
+
+
+def main(argv=None) -> dict[str, list[dict]]:
+    ap = argparse.ArgumentParser(
+        description="The paper's figures and tables as CSV rows.")
+    ap.add_argument("--full", action="store_true",
+                    help="paper-scale sweeps (slow)")
+    ap.add_argument("--smoke", action="store_true",
+                    help="quick subset: " + ",".join(SMOKE))
+    ap.add_argument("--only", default="",
+                    help="comma-separated subset, e.g. fig12,fig13")
+    args = ap.parse_args(argv)
+    only = set(filter(None, args.only.split(","))) or (
+        set(SMOKE) if args.smoke else set(ALL))
+    unknown = only - set(ALL)
+    if unknown:
+        ap.error(f"unknown figures {sorted(unknown)}; known: {list(ALL)}")
+
+    out = {}
+    print("name,metric,value")
+    for key, fn in ALL.items():
+        if key not in only:
+            continue
+        out[key] = fn(full=args.full)
+        for row in out[key]:
+            for metric, value in row.items():
+                if metric == "name":
+                    continue
+                if isinstance(value, float):
+                    value = f"{value:.6g}"
+                print(f"{row['name']},{metric},{value}")
+    return out
+
+
+if __name__ == "__main__":
+    main()

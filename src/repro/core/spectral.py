@@ -54,8 +54,8 @@ with matrix-free matvecs, batched and device-resident throughout:
   available (``lanczos_iters > 0``) for operators where it is not
   vacuous.
 
-Accuracy contract (enforced by the CI settling-accuracy guard,
-``benchmarks.tpu_complexity --settling``): on the tier-1 reference
+Accuracy contract (enforced by ``tests/test_spectral_settling.py``,
+``test_slow_mode_within_2x_of_eig*``): on the tier-1 reference
 matrices — both circuit designs, non-diagonally-dominant SPD included —
 the slow-mode estimate lands within 2x of the exact-eig reference
 (observed: within ~2% once the rightmost residual converges), and

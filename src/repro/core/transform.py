@@ -101,7 +101,8 @@ class Transformed2N(NamedTuple):
         """diag(K_B) — positive entries need a negative-resistance cell.
 
         Eq. 26: K_Bii = -(1/2)(A_ii - K_sii - sum_{j!=i} |A_ji|) is the
-        per-column deviation of (A - K_s) from diagonal dominance.
+        per-column deviation of (A - K_s) from diagonal dominance; at the
+        support node Eq. 22's D adds the other half of K_s00.
         """
         return jnp.diagonal(self.k_b)
 

@@ -14,7 +14,7 @@ ELL ``(indices, weights)`` arrays without ever materializing the
 ``(n, n)`` matrix — grids beyond ~64x64 (n > 4096) stay assemblable in
 O(n) memory.  :func:`mesh_stream` turns the assembly into a seeded
 mixed-size request stream, the serving stack's realistic FEM traffic
-model (see ``benchmarks/newton_fem.py`` and ``examples/fem_poisson.py``).
+model (see ``examples/fem_poisson.py``).
 """
 
 from __future__ import annotations

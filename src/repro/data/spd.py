@@ -81,7 +81,9 @@ def random_sdd(
     with x ~ U[-v, v]:  k_s <= (A_ii + offsum) * v / supply_v.  Solving
     for the diagonal, ``diag >= offsum * (1 + r) / (1 - r)`` with
     r = v/supply_v guarantees the passive path for any such rhs; we add
-    a strictly positive margin on top.
+    a strictly positive margin on top.  The support node (node 0, which
+    Eq. 22's D gives the whole K_s) must dominate 2 K_s, so its diagonal
+    takes ``r = 2 v/supply_v``.
     """
     w = rng.uniform(0.0, g_scale, size=(n, n))
     keep = rng.uniform(size=(n, n)) < density
@@ -89,7 +91,8 @@ def random_sdd(
     w = w + w.T
     a = -w
     colsum = w.sum(axis=0)
-    r = v_range / supply_v
+    r = np.full(n, v_range / supply_v)
+    r[0] *= 2.0
     factor = (1.0 + r) / (1.0 - r)
     a[np.arange(n), np.arange(n)] = colsum * factor + rng.uniform(
         margin * g_scale, (1 + margin) * g_scale, size=n

@@ -128,8 +128,8 @@ def param_specs(logical_tree, rules: Mapping[str, object]):
 #
 # * `shard_system_batch` splits ONE micro-batch's batch axis over every
 #   device (GSPMD NamedSharding).  Each solve then pays a cross-device
-#   dispatch + gather on the request path — measured in BENCH_pr5.json
-#   as an *inverted* device-scaling curve.  Kept for direct
+#   dispatch + gather on the request path — measured on a CPU with
+#   forced host devices as an *inverted* device-scaling curve.  Kept for direct
 #   `solve_batch(mesh=...)` callers with big standalone batches.
 # * `stream_devices` (the serving v2 path) returns the mesh's device
 #   list so the solve service can go data-parallel ACROSS micro-batches

@@ -1,1 +1,1 @@
-"""Launch layer: production mesh, dry-run compiler, train/serve drivers."""
+"""Launch layer: production mesh and the train driver."""

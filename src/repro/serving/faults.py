@@ -20,9 +20,8 @@ This module is the shared chaos mechanism behind that contract:
   ``(dispatch_index, kind)`` schedule.  Both :class:`SolveService` and
   :class:`ServeEngine <repro.serving.engine.ServeEngine>` take it as a
   constructor hook, so the chaos test suite (``tests/test_faults.py``)
-  and the degraded-mode benchmark (``benchmarks/solve_service.py
-  --faults``) exercise the identical failure paths the retry / breaker
-  / fallback machinery defends.
+  exercises the identical failure paths the retry / breaker / fallback
+  machinery defends.
 
 Injected faults are indistinguishable from real ones at the point the
 service observes them: ``device_fault`` raises from the in-flight

@@ -39,9 +39,8 @@ and keeps every device busy while the host builds the next one:
   on the request path.  The v1 service sharded every micro-batch's
   batch axis over the whole mesh (GSPMD collectives + a per-mesh
   compile in the hot loop) and its measured device scaling *inverted*
-  — 15.2 → 3.5 → 0.67 req/s at 1 → 2 → 8 host devices in
-  BENCH_pr5.json; streaming replaces that with embarrassingly parallel
-  placement.
+  — 15.2 → 3.5 → 0.67 req/s at 1 → 2 → 8 forced host devices on a
+  CPU; streaming replaces that with embarrassingly parallel placement.
 * **overlap** — dispatch is split submit/wait
   (:func:`repro.core.solver.solve_batch_submit`): the host-side phase
   (pad, stack, netlist build, error model, assembly) runs eagerly,
@@ -178,8 +177,8 @@ any single-fault model**.  The machinery behind that contract:
   :class:`~repro.serving.faults.FaultInjector` as ``fault_injector``
   and the service injects device faults, NaN solutions, host build
   errors and slow solves *at the exact points real ones surface*;
-  ``stats["fault_injections"]`` counts them.  ``tests/test_faults.py``
-  and ``benchmarks/solve_service.py --faults`` share this mechanism.
+  ``stats["fault_injections"]`` counts them; ``tests/test_faults.py``
+  drives this mechanism.
 
 ``stats`` surfaces the whole story: ``retries``, ``bisections``,
 ``shed``, ``deadline_expired``, ``quarantines``, ``fallbacks``,

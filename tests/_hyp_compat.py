@@ -2,8 +2,8 @@
 
 The tier-1 suite must collect and run on machines without ``hypothesis``
 installed (the container bakes in the JAX/Pallas toolchain only).  Test
-modules import ``given``/``settings``/``st`` from here instead of from
-``hypothesis`` directly:
+modules import ``given``/``settings``/``st``/``example`` from here instead
+of from ``hypothesis`` directly:
 
 * with ``hypothesis`` present this is a pure re-export;
 * without it, ``@given(...)`` falls back to a deterministic sampler:
@@ -12,7 +12,8 @@ modules import ``given``/``settings``/``st`` from here instead of from
   ``FALLBACK_EXAMPLES`` seeded draws (plus the integer endpoints), so
   every property still executes — with far fewer examples than
   hypothesis would run, but deterministically and against the same
-  predicates.  A test using a strategy the fallback cannot sample is
+  predicates; each ``@example`` pinned under the ``@given`` is one more
+  case.  A test using a strategy the fallback cannot sample is
   skipped with that strategy named in the skip reason.
 """
 
@@ -23,7 +24,7 @@ import zlib
 import pytest
 
 try:
-    from hypothesis import given, settings, strategies as st
+    from hypothesis import example, given, settings, strategies as st
 
     HAVE_HYPOTHESIS = True
 except ImportError:  # pragma: no cover - exercised on hypothesis-free CI
@@ -77,6 +78,13 @@ except ImportError:  # pragma: no cover - exercised on hypothesis-free CI
     def settings(*args, **kwargs):
         return lambda fn: fn
 
+    def example(**kwargs):
+        def deco(fn):
+            fn._hyp_examples = [kwargs, *getattr(fn, "_hyp_examples", [])]
+            return fn
+
+        return deco
+
     def given(*args, **kwargs):
         if args or not kwargs:
             # the suite only uses keyword strategies; anything else has
@@ -109,10 +117,13 @@ except ImportError:  # pragma: no cover - exercised on hypothesis-free CI
                     )(fn)
                 columns.append(draws)
             cases = list(zip(*columns))
+            pinned = [tuple(ex[k] for k in names)
+                      for ex in getattr(fn, "_hyp_examples", [])]
             return pytest.mark.parametrize(
                 ",".join(names),
-                cases,
-                ids=[f"fallback{i}" for i in range(len(cases))],
+                cases + pinned,
+                ids=[f"fallback{i}" for i in range(len(cases))]
+                + [f"example{i}" for i in range(len(pinned))],
             )(fn)
 
         return deco

@@ -128,6 +128,43 @@ def test_ell_sweep_matches_dense_sweep():
     np.testing.assert_allclose(xe, x, rtol=0.02, atol=1e-3)
 
 
+PARITY_SIZES = (16, 48)
+
+
+def _parity_sweep_batch(n):
+    """The n-sweep's three systems at size ``n``, the second negated
+    (non-PD): one generator (seed 123) draws every size in order."""
+    rng = np.random.default_rng(123)
+    for size in PARITY_SIZES[:PARITY_SIZES.index(n) + 1]:
+        nets, xs = [], []
+        for k in range(3):
+            a = random_spd(rng, size)
+            if k == 1:
+                a = -a
+            x, b = random_rhs_from_solution(rng, a)
+            nets.append(build_proposed(a, b))
+            xs.append(x)
+    return nets, np.stack(xs)
+
+
+@pytest.mark.parametrize("n", PARITY_SIZES)
+def test_ell_sweep_parity_over_n_sweep(n):
+    """Dense <-> ELL drift over an n-sweep with a non-PD system: assembly
+    to f64 round-off, identical step counts (the non-PD system runs out
+    the budget on both paths), f32-level states of the settled ones."""
+    nets, x = _parity_sweep_batch(n)
+    dense = engine.assemble_batch(nets)
+    ell = engine.assemble_batch_ell(nets)
+    scale = np.abs(dense.m).max()
+    np.testing.assert_allclose(ell.to_dense(), dense.m, rtol=0.0,
+                               atol=1e-12 * scale)
+    sd, xd, _, _ = engine.euler_settle_batch(dense, x, max_steps=40_000)
+    se, xe, _, _ = engine.euler_settle_batch(ell, x, max_steps=40_000)
+    np.testing.assert_array_equal(sd, se)
+    assert sd[1] == 40_000 and np.all(sd[[0, 2]] < 40_000)
+    np.testing.assert_allclose(xe[[0, 2]], xd[[0, 2]], rtol=0.0, atol=2e-5)
+
+
 def test_ell_sweep_non_block_multiple_n():
     """Regression: ELL padding is exact for nz far from 128 multiples.
     Every launch is counted by mode (here all interpreted)."""

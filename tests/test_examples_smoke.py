@@ -1,12 +1,13 @@
 """CI smoke runs of the end-to-end example drivers.
 
-Both examples expose ``main(argv)`` with a ``--smoke`` configuration
+Each example exposes ``main(argv)`` with a ``--smoke`` configuration
 sized for seconds-scale CI; these tests pin the example entry points to
 the library APIs (renames/regressions in either break here first) and
 assert the workload actually exercised the analog engine — the
 train_lm probe checks the refresh accounting (one batched solve per
 refresh on one cached pattern), which a silently-skipping block filter
-would zero out.
+would zero out; the paper_figs probe checks the Fig. 8 stability
+verdicts and the Sec. IV-A D-policy comparison.
 """
 
 import importlib
@@ -43,3 +44,23 @@ def test_train_lm_example_smoke():
     assert rs.systems_solved > 0
     assert rs.pattern_derivations == 1
     an.reset_refresh_stats()
+
+
+def test_paper_figs_example_smoke(capsys):
+    out = _load("paper_figs").main(["--smoke"])
+    assert set(out) == {"fig8", "fig10", "table1", "table2", "dpolicy"}
+    assert capsys.readouterr().out.startswith("name,metric,value\n")
+    pd, nd = out["fig8"]
+    # Fig. 8: the PD system settles to x; its negation is unstable and
+    # drives the op-amps into the rails
+    assert pd["lti_stable"] == 1 and pd["amp_saturated"] == 0
+    assert pd["err_fullscale"] < 1e-2
+    assert nd["lti_stable"] == 0 and nd["amp_saturated"] == 1
+    # Fig. 10: every beta variant settled, error grows with beta
+    assert all(0 < r["settle_us"] < float("inf") for r in out["fig10"])
+    per_beta = [r["err_pct"] for r in out["fig10"][:5]]
+    assert per_beta == sorted(per_beta)
+    # Sec. IV-A: Eq. 22 keeps every transformed system PD, Gremban not
+    pct = {r["name"]: r["pd_preserved_pct"] for r in out["dpolicy"]}
+    assert pct["dpolicy_proposed"] == 100.0
+    assert pct["dpolicy_gremban"] < 100.0

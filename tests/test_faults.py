@@ -250,6 +250,8 @@ def _chaos_run(*, rates, n_streams=1, n_requests=18, seed=11, **svc_kw):
     {"nonfinite": 0.2},
     {"build_error": 0.2},
     {"device_fault": 0.1, "nonfinite": 0.05, "build_error": 0.05},
+    # the same 50/25/25 split at a 5% total rate
+    {"device_fault": 0.025, "nonfinite": 0.0125, "build_error": 0.0125},
 ])
 def test_service_chaos_exactly_once_under_faults(rates):
     svc, res, want = _chaos_run(rates=rates, max_attempts=4)

@@ -200,6 +200,50 @@ def test_refine_none_keeps_legacy_contract():
     assert "refine_iters" not in res.info
 
 
+# ------------------------------------------------------ precision grid
+# (bits, pot_tol) -> (analog-path fraction, mean refinement passes) of
+# the precision grid's cells, either sweep dtype, at the 1e-10 recovery
+# contract; a cell may fall short of either by GRID_SLACK
+PRECISION_CELLS = {
+    (4, 0.0): (0.0, 2.8333333333333335),
+    (4, 0.01): (0.0, 3.1666666666666665),
+    (8, 0.0): (1.0, 5.666666666666667),
+    (8, 0.01): (1.0, 6.833333333333333),
+}
+GRID_SLACK = 0.25
+
+
+@pytest.mark.parametrize("sweep_dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("bits,pot_tol", list(PRECISION_CELLS))
+def test_precision_grid_cell(bits, pot_tol, sweep_dtype):
+    """One cell of the precision grid: six general SPD systems (n = 24,
+    density 0.6) on seeded quantized/noisy hardware, graded recovery
+    with the matrix-free settling probe against the raw analog answer.
+    Every system recovers to 1e-10; on 8-bit 1% hardware all of them
+    through the analog path alone, with no digital fallback."""
+    rng = np.random.default_rng(0)
+    a = np.stack([random_spd(rng, 24, density=0.6) for _ in range(6)])
+    b = np.stack([random_rhs_from_solution(rng, ak)[1] for ak in a])
+    ni = NonIdealities(pot_bits=bits, pot_tol=pot_tol, seed=0)
+    raw = solve_batch(a, b, method="analog_2n", nonideal=ni, fallback="none")
+    res = solve_batch(
+        a, b, method="analog_2n", nonideal=ni, refine=BUDGET,
+        fallback="cholesky", compute_settling=True, settle_method="euler",
+        settle_matrix_free=True, x_ref=raw.x, settle_max_steps=100_000,
+        sweep_dtype=sweep_dtype,
+    )
+    rel = np.asarray(res.info["residual"])
+    path = np.asarray(res.info["precision_path"])
+    analog_frac = float(np.mean(np.isin(path, ("analog", "refined"))))
+    iters_mean = float(np.mean(res.info["refine_iters"]))
+    want_frac, want_iters = PRECISION_CELLS[bits, pot_tol]
+    assert float(rel.max()) <= RECOVER_TOL
+    assert analog_frac >= (1.0 - GRID_SLACK) * want_frac
+    assert iters_mean <= (1.0 + GRID_SLACK) * want_iters
+    if (bits, pot_tol) == (8, 0.01):
+        assert analog_frac == 1.0
+
+
 # -------------------------------------------------- serving contract
 def test_service_precision_contract_and_counters():
     svc = SolveService(batch_slots=4, refine=BUDGET)

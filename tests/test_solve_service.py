@@ -677,3 +677,56 @@ def test_service_streams_over_forced_devices():
     assert out.returncode == 0, out.stderr[-4000:]
     res = json.loads(out.stdout.strip().splitlines()[-1])
     assert res["devices"] == 4 and res["worst"] < 1e-9
+
+
+_MIX_PROG = textwrap.dedent("""
+    import os
+    os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=4"
+    import json
+    import sys
+    import numpy as np
+    import jax
+    sys.path.insert(0, os.getcwd())
+    from chip_smoke import build_stream
+    from repro.core.solver import solve
+    from repro.serving.solve_service import SolveService
+
+    assert len(jax.devices()) == 4
+    stream = build_stream(0, repeat=1)
+    direct = [solve(s["a"], s["b"], method=s["method"]).x for s in stream]
+    worst = {}
+    for slots, streams, inflight in ((2, 1, 2), (4, 1, 1), (4, 1, 2),
+                                     (4, 2, 2), (4, 4, 2)):
+        svc = SolveService(batch_slots=slots, n_devices=streams,
+                           inflight_per_device=inflight)
+        rids = [svc.submit(s["a"], s["b"], method=s["method"])
+                for s in stream]
+        res = svc.drain()
+        assert set(res) == set(rids) and len(svc.queue) == 0
+        st = svc.stats
+        assert st["devices"] == streams
+        assert min(st["device_micro_batches"]) > 0
+        worst[f"{slots}/{streams}/{inflight}"] = max(
+            float(np.abs(res[r].x - x).max()) for r, x in zip(rids, direct))
+    print(json.dumps(worst))
+""")
+
+
+def test_service_mixed_stream_over_forced_devices():
+    """chip_smoke's nine-system mix (n 16-192, both designs, an SDD
+    system, Cholesky; n = 24 pads into the 32 bucket) through bucketed,
+    padded services at 2 and 4 slots, serial and double-buffered, over
+    1, 2 and 4 forced host device streams: every answer within 1e-9 of
+    a direct solve()."""
+    env = dict(os.environ)
+    env["PYTHONPATH"] = "src"
+    # forced host devices: the child must never reach for an accelerator
+    env["JAX_PLATFORMS"] = "cpu"
+    out = subprocess.run(
+        [sys.executable, "-c", _MIX_PROG],
+        capture_output=True, text=True, env=env, timeout=600,
+        cwd=os.path.dirname(os.path.dirname(os.path.abspath(__file__))),
+    )
+    assert out.returncode == 0, out.stderr[-4000:]
+    worst = json.loads(out.stdout.strip().splitlines()[-1])
+    assert len(worst) == 5 and max(worst.values()) < 1e-9, worst

@@ -26,7 +26,11 @@ import pytest
 
 from repro.core.solver import solve, solve_batch, solve_batch_submit
 from repro.data.spd import random_rhs_from_solution, random_spd
-from repro.optim.batched_newton import BatchedNewtonConfig, newton_batch
+from repro.optim.batched_newton import (
+    BatchedNewtonConfig,
+    newton_batch,
+    newton_looped,
+)
 from repro.serving import SessionRoundError, SolveService
 from repro.serving.faults import FaultInjector, FaultPlan, SolveError
 
@@ -164,6 +168,33 @@ def test_session_newton_matches_direct_batched_run():
     assert tr_svc.solve_rounds == tr_svc.iterations.max()
     # one sparsity class across every round -> one pattern derivation
     assert sess.pattern_derivations == 1
+
+
+def test_session_newton_matches_looped_reference():
+    """Newton rounds through a session follow the one-system-at-a-time
+    looped reference: the same iteration counts, iterates to last-ulp."""
+    rng = np.random.default_rng(0)
+    bsz, n = 6, 8
+    t = rng.normal(size=(bsz, n))
+    m = rng.normal(size=(bsz, n, n)) / np.sqrt(n)
+    q = 0.5 * np.einsum("bij,bkj->bik", m, m) + np.eye(n)
+    eye = np.eye(n)
+
+    def grad_hess(x):
+        d = x - t
+        return (
+            np.einsum("bij,bj->bi", q, d) + d ** 3,
+            q + (3.0 * d ** 2)[:, :, None] * eye,
+        )
+
+    cfg = BatchedNewtonConfig(method="analog_2n", tol=1e-8)
+    sess = SolveService(batch_slots=bsz).session(method="analog_2n")
+    tr_svc = newton_batch(grad_hess, np.zeros((bsz, n)), cfg, rounds=sess)
+    tr_ref = newton_looped(grad_hess, np.zeros((bsz, n)), cfg)
+
+    assert tr_svc.converged.all()
+    assert np.array_equal(tr_svc.iterations, tr_ref.iterations)
+    assert np.abs(tr_svc.x - tr_ref.x).max() <= 1e-12
 
 
 def test_session_preserves_interleaved_foreign_traffic():

@@ -4,6 +4,8 @@ stability certificates, the spectral sweep-chunk schedule, solve() /
 solve_batch settling-kwarg parity, BatchSolveResult indexing, and the
 CrosspointLayout DC round-trip."""
 
+import zlib
+
 import numpy as np
 import pytest
 
@@ -48,6 +50,46 @@ def test_slow_mode_within_2x_of_eig(builder):
     ratio = sb.slow_re / true_slow
     assert np.all(sb.slow_re < 0)
     assert np.all((ratio > 0.5) & (ratio < 2.0)), ratio
+
+
+# label -> (design, n, systems) of the settling-accuracy guard's set
+REFERENCE_SET = {
+    "proposed": (build_proposed, 14, 4),
+    "proposed_sparse": (build_proposed, 20, 3),
+    "preliminary": (build_preliminary, 12, 3),
+    "sdd": (build_proposed, 12, 3),
+    "non_pd": (build_proposed, 10, 3),
+}
+
+
+@pytest.mark.parametrize("label", list(REFERENCE_SET))
+def test_slow_mode_within_2x_of_eig_reference_set(label):
+    """The settling-accuracy guard's reference set: the slow-mode
+    estimate stays within [0.5, 2.0]x of the exact rightmost eigenvalue
+    (density 0.4 in ``proposed_sparse``, an SDD last system in ``sdd``),
+    and the estimator flags the unstable system (``non_pd``: the last
+    one negated)."""
+    builder, n, count = REFERENCE_SET[label]
+    rng = np.random.default_rng(zlib.crc32(label.encode()))
+    nets = []
+    for k in range(count):
+        a = random_spd(rng, n,
+                       density=0.4 if label == "proposed_sparse" else 1.0)
+        if label == "non_pd" and k == count - 1:
+            a = -a
+        if label == "sdd" and k == count - 1:
+            a = random_sdd(rng, n)
+        _x, b = random_rhs_from_solution(rng, a)
+        nets.append(builder(a, b))
+    dense = engine.assemble_batch(nets)
+    sb = spectral.spectral_bounds(engine.assemble_batch_ell(nets))
+    lam = np.linalg.eigvals(dense.m)
+    unstable = lam.real.max(axis=1) >= 0
+    assert unstable.sum() == (label == "non_pd")
+    assert np.all(sb.slow_re[unstable] >= 0)
+    for k in np.flatnonzero(~unstable):
+        ratio = sb.slow_re[k] / lam[k].real[lam[k].real < 0].max()
+        assert 0.5 <= ratio <= 2.0, (k, ratio)
 
 
 def test_slow_mode_settle_time_within_2x():
