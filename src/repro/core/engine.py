@@ -1640,7 +1640,6 @@ def euler_settle_batch(
     reduction from the final chunk, and the per-system step size.
     """
     from repro.kernels.ops import (
-        SWEEP_STATE_LIMIT,
         ell_kernel_operands,
         ell_transient_sweep,
         sweep_backend,
@@ -1743,16 +1742,12 @@ def euler_settle_batch(
                     jnp.asarray(mt).astype(jnp.bfloat16).astype(jnp.float32)
                 )
 
-            # hoist the kernel-shape prep out of the chunk loop:
-            # block-pad once (both dense paths) and pre-transpose for
-            # the VMEM-resident sweep
-            fused = nz <= SWEEP_STATE_LIMIT
+            # hoist the kernel-shape prep out of the chunk loop: block-pad
+            # once; both dense paths take the operator as assembled
             size = nz + (-nz) % 128
             if size != nz:
                 mt = np.pad(mt, ((0, 0), (0, size - nz), (0, size - nz)))
                 ct = np.pad(ct, ((0, 0), (0, size - nz)))
-            if fused:
-                mt = mt.transpose(0, 2, 1)
 
             with span("core.transfer"):
                 if z0_full is not None:
@@ -1760,13 +1755,12 @@ def euler_settle_batch(
                         z0_full, ((0, 0), (0, size - nz))).astype(np.float32))
                 else:
                     z = jnp.zeros((b_count, size), dtype=jnp.float32)
-                mt_j = jnp.asarray(np.ascontiguousarray(mt))
+                mt_j = jnp.asarray(mt)
                 ct_j = jnp.asarray(ct)
 
             def step_chunk(zz, n):
                 return transient_sweep(
                     mt_j, zz, ct_j, n_steps=n, interpret=interpret,
-                    m_transposed=fused,
                 )
 
     steps, x_final, res = _settle_loop(

@@ -124,9 +124,34 @@ def transient_step_batched(
     return out[:, 0, :n], jnp.max(jnp.abs(dz), axis=(1, 2))
 
 
-# fused-sweep VMEM budget: the double-buffered (n, n) f32 operator of one
-# system must fit on-chip (see transient_step.sweep_vmem_bytes)
-SWEEP_STATE_LIMIT = 1792
+# VMEM of one TensorCore where no TPU is attached (CPU rehearsals,
+# interpret mode): the v5e's, so that a rehearsal takes the dense path
+# the chip takes at the same nz
+DEFAULT_VMEM_BYTES = 128 << 20
+
+# dense settle chunks launched, by the path that ran them
+DENSE_SWEEP_CHUNKS = {"resident": 0, "tiled": 0}
+
+
+@functools.cache
+def vmem_capacity_bytes() -> int:
+    """VMEM of one TensorCore of the default device."""
+    if jax.default_backend() != "tpu":
+        return DEFAULT_VMEM_BYTES
+    from jax.experimental.pallas import tpu as pltpu
+
+    return pltpu.get_tpu_info().vmem_capacity_bytes
+
+
+def sweep_state_limit() -> int:
+    """The largest state count (a multiple of 128) whose VMEM-resident
+    sweep fits the chip: :func:`repro.kernels.transient_step.
+    sweep_vmem_bytes` (double-buffered operator plus 16 MiB of headroom)
+    within :func:`vmem_capacity_bytes`.  3712 on a v5e (128 MiB)."""
+    n = 0
+    while _st.sweep_vmem_bytes(n + 128) <= vmem_capacity_bytes():
+        n += 128
+    return n
 
 # ---------------------------------------------------------------------------
 # Dense <-> ELL crossover model
@@ -172,13 +197,17 @@ def sweep_backend(nz: int, k: int | None) -> str:
     """Pick the transient-sweep backend for an operator family.
 
     ``k`` is the ELL slot width (None for a dense-only caller).
-    Returns ``"ell"`` (ELL-SpMV sweep), ``"dense"`` (fused dense sweep,
-    operator VMEM-resident) or ``"dense-step"`` (tiled dense step
-    kernel, looped on the device).
+    Returns ``"ell"`` (ELL-SpMV sweep), ``"dense"`` (the operator
+    VMEM-resident for a whole chunk: it crosses HBM once per chunk) or
+    ``"dense-step"`` (the tiled step kernel looped on the device: the
+    operator crosses HBM every step).  The dense side is chosen from
+    the chip's VMEM (:func:`sweep_state_limit`): every nz whose resident
+    operator fits takes it, and only larger ones pay the per-step
+    stream.
     """
     if k is not None and k < ELL_FILL_CUTOFF * nz:
         return "ell"
-    return "dense" if nz <= SWEEP_STATE_LIMIT else "dense-step"
+    return "dense" if nz <= sweep_state_limit() else "dense-step"
 
 
 def ell_kernel_operands(
@@ -248,58 +277,47 @@ def transient_sweep(
     n_steps: int,
     dt: float = 1.0,
     interpret: bool | None = None,
-    m_transposed: bool = False,
     sweep_dtype: str = "float32",
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """``n_steps`` fused batched Euler steps; m (B, n, n), z/c (B, n).
+    """``n_steps`` batched Euler steps in one launch; m (B, n, n), z/c (B, n).
 
-    Uses the VMEM-resident sweep kernel while the per-system operator
-    fits on-chip, else falls back to the tiled batched step kernel
-    looped on the device (:func:`repro.kernels.transient_step.
-    tiled_transient_sweep_pallas`).  Either way one call is one launch,
-    whatever ``n_steps``.  Returns ``(z', res)`` with the per-system
-    residual ``max_i |M z' + c|_i`` evaluated at the final state.
-
-    ``m_transposed=True`` asserts the caller already block-padded every
-    operand and passed ``m[b] = M_b.T`` — the loop-hoisted fast path for
-    sweeps that launch many chunks over the same operator batch (that
-    path expects the caller to have applied ``sweep_dtype`` rounding to
-    ``m`` once, outside the chunk loop).
+    Pads every operand to a multiple of 128 (nothing to do for a caller
+    that padded once, outside its chunk loop), then runs the backend
+    :func:`sweep_backend` picks: the VMEM-resident sweep
+    (:func:`repro.kernels.transient_step.transient_sweep_pallas`) while
+    the operator fits on-chip, else the tiled step kernel looped on the
+    device (:func:`repro.kernels.transient_step.
+    tiled_transient_sweep_pallas`).  ``n_steps`` is traced on both, so
+    every chunk length shares one executable; :data:`DENSE_SWEEP_CHUNKS`
+    counts the launches by path.  Returns ``(z', res)`` with the
+    per-system residual ``max_i |M z' + c|_i`` evaluated at the final
+    state.
 
     ``sweep_dtype="bfloat16"`` rounds the dense operator through bf16
     before the f32 sweep — the same storage-precision semantics as the
-    ELL bf16 kernels (the dense MXU kernels accumulate in f32 either
-    way, so rounding the weights is the entire dtype effect).
+    ELL bf16 kernels (the dense kernels accumulate in f32 either way,
+    so rounding the weights is the entire dtype effect).
     """
     interpret = _resolve_interpret(interpret)
     assert sweep_dtype in _ell.SWEEP_DTYPES, sweep_dtype
-    if sweep_dtype == "bfloat16" and not m_transposed:
+    if sweep_dtype == "bfloat16":
         m = m.astype(jnp.bfloat16).astype(jnp.float32)
-    bsz, n, _ = m.shape
-    if m_transposed:
-        out, dz = _st.transient_sweep_pallas(
-            m, z[:, None, :], c[:, None, :], n_steps=n_steps, dt=dt,
-            interpret=interpret,
-        )
-        return out[:, 0], jnp.max(jnp.abs(dz), axis=(1, 2))
-    if n > SWEEP_STATE_LIMIT:
-        bm, bk = _st.DEFAULT_BATCHED_BLOCK
-        size = n + (-n) % math.lcm(bm, bk)
-        out, res = _st.tiled_transient_sweep_pallas(
-            _pad_to(m, (1, size, size)), _pad_to(z, (1, size))[:, None, :],
-            _pad_to(c, (1, size))[:, None, :], jnp.int32(n_steps), dt=dt,
-            interpret=interpret,
-        )
-        return out[:, 0, :n], res
-    size = n + (-n) % 128
-    mp = _pad_to(m, (1, size, size))
-    zp = _pad_to(z, (1, size))
-    cp = _pad_to(c, (1, size))
-    out, dz = _st.transient_sweep_pallas(
-        mp.transpose(0, 2, 1), zp[:, None, :], cp[:, None, :],
-        n_steps=n_steps, dt=dt, interpret=interpret,
+    n = m.shape[1]
+    size = n + (-n) % 128      # both kernels' block edge
+    m = _pad_to(m, (1, size, size))
+    z = _pad_to(z, (1, size))
+    c = _pad_to(c, (1, size))
+    if sweep_backend(size, None) == "dense":
+        DENSE_SWEEP_CHUNKS["resident"] += 1
+        out, res = _st.transient_sweep_pallas(
+            m, z, c, jnp.int32(n_steps), dt=dt, interpret=interpret)
+        return out[:, :n], res
+    DENSE_SWEEP_CHUNKS["tiled"] += 1
+    out, res = _st.tiled_transient_sweep_pallas(
+        m, z[:, None, :], c[:, None, :], jnp.int32(n_steps), dt=dt,
+        interpret=interpret,
     )
-    return out[:, 0, :n], jnp.max(jnp.abs(dz), axis=(1, 2))
+    return out[:, 0, :n], res
 
 
 def spd_transform_arrays(

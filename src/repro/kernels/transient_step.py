@@ -23,22 +23,27 @@ Batched variants (one state vector per system, per-system operator):
   c_b`` (the steady-state residual; zero exactly at the operating
   point), so the driving sweep can test convergence without a second
   pass over M.
-* :func:`transient_sweep_pallas` — ``n_steps`` fused steps with the
-  whole per-system operator VMEM-resident (grid over the batch only):
-  the physics iterates on-chip and M crosses HBM once per *chunk*
-  instead of once per step.  Usable while the double-buffered
-  ``n^2 * 4``-byte operator fits in VMEM (``sweep_vmem_bytes``).
+* :func:`transient_sweep_pallas` — ``n_steps`` steps with the whole
+  per-system operator VMEM-resident (grid over the batch only): the
+  physics iterates on-chip and M crosses HBM once per *chunk* instead
+  of once per step.  Each step's matvec is exact f32 on the VPU, every
+  operator vreg read once (a one-row MXU product would pay a weight
+  load per 128x128 tile, as the tiled kernel does).  It serves every
+  nz whose double-buffered ``nz^2 * 4``-byte operator fits the chip's
+  VMEM (``sweep_vmem_bytes``; up to nz = 3712 on a v5e).
 * :func:`tiled_transient_sweep_pallas` — the fallback beyond that:
   ``n_steps`` launches of the tiled batched step kernel inside one
   compiled device program (a device-side loop), so a chunk costs the
   host one dispatch however many steps it runs.  M crosses HBM once
-  per step.
+  per step, 128x128 tile by tile.
 
-The batched kernels run their matvecs on the MXU at ``HIGHEST``
-precision (f32 semantics; the default single bf16 pass would move the
-settle point by ~1e-3) and take states as ``(B, 1, n)``.  Callers go
-through the auto-padding wrappers in :mod:`repro.kernels.ops`; the raw
-kernels assert block-multiple shapes.
+Both sweeps take a traced step count, so one executable serves every
+chunk length.  The step kernels run their matvecs on the MXU at
+``HIGHEST`` precision (f32 semantics; the default single bf16 pass
+would move the settle point by ~1e-3) and take states as ``(B, 1,
+n)``; the resident sweep takes ``(B, n)``.  Callers go through the
+auto-padding wrappers in :mod:`repro.kernels.ops`; the raw kernels
+assert block-multiple shapes.
 
 Dense <-> ELL crossover
 -----------------------
@@ -56,10 +61,12 @@ crossover model:
   their true degree.  The switch picks ELL whenever
   ``K < ELL_FILL_CUTOFF * nz`` (cutoff 0.5 = the break-even of the
   2-arrays-per-slot format).
-* **VMEM budget** — the fused dense sweep holds the double-buffered
-  ``nz^2 * 4``-byte operator per system on-chip (``SWEEP_STATE_LIMIT``)
-  and degrades to its per-step tiled kernel beyond; the ELL step
-  streams one row block of slots at a time and has no size limit.
+* **VMEM budget** — the resident dense sweep holds the double-buffered
+  ``nz^2 * 4``-byte operator per system on-chip; the path rule
+  (:func:`repro.kernels.ops.sweep_state_limit`) reads the chip's VMEM
+  capacity and sends larger operators to the per-step tiled kernel;
+  the ELL step streams one row block of slots at a time and has no
+  size limit.
 * **gather cost** — the ELL row reduction pays one gather per slot; on
   sparse systems the traffic win dominates, at fill ratios near the
   cutoff the dense MXU/VPU stream wins, which is why the switch is by
@@ -150,12 +157,6 @@ _I0 = np.int32(0)
 _HIGHEST = jax.lax.Precision.HIGHEST
 
 
-def _row_matvec(z, m_t):
-    """``z @ m_t`` for a row vector: ``(1, k) x (k, bm) -> (1, bm)``, f32."""
-    return jnp.dot(z, m_t, precision=_HIGHEST,
-                   preferred_element_type=jnp.float32)
-
-
 def _step_batched_kernel(
     m_ref, zk_ref, zi_ref, c_ref, out_ref, dz_ref, acc_ref,
     *, n_k_blocks: int, dt: float
@@ -228,82 +229,111 @@ def transient_step_batched_pallas(
 
 
 # ---------------------------------------------------------------------------
-# Fused multi-step sweep: whole per-system operator VMEM-resident
+# Resident multi-step sweep: whole per-system operator VMEM-resident
 # ---------------------------------------------------------------------------
+#
+# States live as (n / 128, 128) lane rows: row j holds z[128 j : 128 j + 128],
+# so one row block of the operator meets its slice of z with a sublane
+# broadcast, and the kernel indexes rows only (dynamic sublane offsets).
+
+_LANES = 128
 
 
-# operator rows per MXU pass of the fused sweep's matvec: bounds the
-# in-kernel temporaries (the f32 HIGHEST-precision split of one chunk)
-# instead of materializing the whole (n, n) operator as a value
-_SWEEP_ROW_CHUNK = 128
+def _resident_matvec(m_ref, z_ref, c_ref, dz_ref):
+    """``dz = M z + c`` against the resident ``(1, n, n)`` operator block.
+
+    Exact f32 on the VPU: for each 128-row block of M, the products of
+    its ``(128, 128)`` tiles with their ``z`` lane row accumulate into
+    one ``(128, 128)`` partial (every operator vreg is read once); one
+    transpose and a sublane sum turn the partial's row sums into the
+    block's ``(1, 128)`` lane row of ``dz``.
+    """
+    nb = z_ref.shape[0]
+
+    def row_block(i):
+        rows = pl.ds(pl.multiple_of(i * np.int32(_LANES), _LANES), _LANES)
+        acc = m_ref[0, rows, 0:_LANES] * z_ref[0:1, :]
+        for j in range(1, nb):
+            cols = slice(j * _LANES, (j + 1) * _LANES)
+            acc = acc + m_ref[0, rows, cols] * z_ref[j:j + 1, :]
+        dz_ref[pl.ds(i, 1), :] = (jnp.sum(acc.T, axis=0, keepdims=True)
+                                  + c_ref[pl.ds(i, 1), :])
+        return i + np.int32(1)
+
+    # a while loop keeps the block index int32 (a fori_loop over static
+    # bounds counts in int64 under the package's x64 mode)
+    jax.lax.while_loop(lambda i: i < nb, row_block, _I0)
 
 
-def _sweep_kernel(mt_ref, z_ref, c_ref, out_ref, dz_ref, *, n_steps: int, dt: float):
-    n = mt_ref.shape[1]
-    c = c_ref[0].astype(jnp.float32)                  # (1, n)
+def _resident_sweep_kernel(n_ref, m_ref, z_ref, c_ref, out_ref, dz_ref,
+                           *, dt: float):
+    # the output blocks hold the state and the residual while they sweep
+    c = c_ref.at[_I0]
+    z, dz = out_ref.at[_I0], dz_ref.at[_I0]
+    z[...] = z_ref[0]
 
-    def residual(zz):                                 # M z + c, (1, n)
-        acc = c
-        for k0 in range(0, n, _SWEEP_ROW_CHUNK):
-            rows = slice(k0, k0 + _SWEEP_ROW_CHUNK)
-            acc = acc + _row_matvec(
-                zz[:, rows], mt_ref[0, rows, :].astype(jnp.float32)
-            )
-        return acc
+    def step(_, carry):
+        _resident_matvec(m_ref, z, c, dz)
+        z[...] = z[...] + dt * dz[...]
+        return carry
 
-    z = jax.lax.fori_loop(
-        0, n_steps, lambda _, zz: zz + dt * residual(zz),
-        z_ref[0].astype(jnp.float32),
-    )
-    out_ref[0] = z.astype(out_ref.dtype)
-    dz_ref[0] = residual(z)
+    jax.lax.fori_loop(_I0, n_ref[0], step, None)
+    _resident_matvec(m_ref, z, c, dz)
 
 
 def sweep_vmem_bytes(n: int) -> int:
-    """Scoped VMEM the fused sweep needs: the double-buffered ``(n, n)``
-    f32 operator block plus headroom for the state blocks and the
-    per-chunk matvec temporaries."""
+    """Scoped VMEM the resident sweep asks for: the double-buffered
+    ``(n, n)`` f32 operator block plus headroom for the state blocks and
+    the matvec's temporaries."""
     return 2 * n * n * 4 + (16 << 20)
 
 
-@functools.partial(jax.jit, static_argnames=("n_steps", "dt", "interpret"))
+@functools.partial(jax.jit, static_argnames=("dt", "interpret"))
 def transient_sweep_pallas(
-    m_t: jnp.ndarray,
+    m: jnp.ndarray,
     z: jnp.ndarray,
     c: jnp.ndarray,
+    n_steps,
     *,
-    n_steps: int,
     dt: float = 1.0,
     interpret: bool = False,
 ) -> tuple[jnp.ndarray, jnp.ndarray]:
-    """``n_steps`` fused Euler steps per system, operator VMEM-resident.
+    """``n_steps`` Euler steps per system, operator VMEM-resident.
 
-    ``m_t`` is the batch of *transposed* operators (``m_t[b] = M_b.T``)
-    so the in-kernel update is a plain row-vector matmul; ``z``/``c``
-    are ``(B, 1, n)``.  Returns ``(z', dz)`` with ``dz = M z' + c`` at
-    the final state — reduce ``max |dz|`` for the settling check.
+    ``m`` is ``(B, n, n)`` (not transposed), ``z``/``c`` ``(B, n)``,
+    ``n`` a multiple of 128.  The grid runs over the batch: each
+    system's operator crosses HBM once per call (the next system's
+    block prefetches while this one sweeps) and every step runs against
+    the resident block.  ``n_steps`` is a traced count, so every chunk
+    length shares one executable.  Returns ``(z', res)`` with ``res[b] =
+    max_i |M_b z'_b + c_b|_i`` — the settling-check reduction at the
+    final state.
     """
-    bsz, n, n2 = m_t.shape
-    assert n == n2 and z.shape == (bsz, 1, n) and c.shape == z.shape, (
-        m_t.shape, z.shape, c.shape)
-    assert n % 128 == 0, m_t.shape
-    row = pl.BlockSpec((1, 1, n), lambda b: (b, _I0, _I0))
-
-    return pl.pallas_call(
-        functools.partial(_sweep_kernel, n_steps=int(n_steps), dt=float(dt)),
-        grid=(bsz,),
-        in_specs=[pl.BlockSpec((1, n, n), lambda b: (b, _I0, _I0)), row, row],
-        out_specs=[row, row],
-        out_shape=[
-            jax.ShapeDtypeStruct(z.shape, z.dtype),
-            jax.ShapeDtypeStruct(z.shape, jnp.float32),
-        ],
+    bsz, n, n2 = m.shape
+    assert n == n2 and z.shape == (bsz, n) and c.shape == z.shape, (
+        m.shape, z.shape, c.shape)
+    assert n % _LANES == 0, m.shape
+    rows = (bsz, n // _LANES, _LANES)
+    state = pl.BlockSpec((1,) + rows[1:], lambda b, _n: (b, _I0, _I0))
+    out, dz = pl.pallas_call(
+        functools.partial(_resident_sweep_kernel, dt=float(dt)),
+        grid_spec=pltpu.PrefetchScalarGridSpec(
+            num_scalar_prefetch=1,
+            grid=(bsz,),
+            in_specs=[pl.BlockSpec((1, n, n), lambda b, _n: (b, _I0, _I0)),
+                      state, state],
+            out_specs=[state, state],
+        ),
+        out_shape=[jax.ShapeDtypeStruct(rows, jnp.float32)] * 2,
         compiler_params=pltpu.CompilerParams(
             vmem_limit_bytes=sweep_vmem_bytes(n)
         ),
         name="dense_sweep",
         interpret=interpret,
-    )(m_t, z, c)
+    )(jnp.reshape(jnp.asarray(n_steps, jnp.int32), (1,)),
+      m.astype(jnp.float32), z.astype(jnp.float32).reshape(rows),
+      c.astype(jnp.float32).reshape(rows))
+    return out.reshape(bsz, n), jnp.max(jnp.abs(dz), axis=(1, 2))
 
 
 # ---------------------------------------------------------------------------

@@ -113,7 +113,11 @@ cells stamped and the crosspoint branches between the ``x`` and ``-x``
 halves (the off-diagonals of ``K_B``; none for an M-matrix), and
 ``stats["settle_steps_swept"]`` the Euler steps the device ran, per
 micro-batch up to its slowest system (pad rows included), over the
-unpacked micro-batches of the euler settle.
+unpacked micro-batches of the euler settle.  Two more say which dense
+sweep ran them: ``stats["settle_chunks_resident"]`` and
+``stats["settle_chunks_tiled"]`` count the chunk launches of the
+VMEM-resident sweep and of the tiled per-step sweep
+(:func:`repro.kernels.ops.sweep_backend`) during this service's drains.
 
 Failure semantics — the delivery contract
 -----------------------------------------
@@ -258,6 +262,7 @@ from repro.core.solver import (
     _build_nets,
     solve_batch_submit,
 )
+from repro.kernels import ops
 from repro.kernels.ell_transient import SWEEP_DTYPES
 from repro.core.specs import DEFAULT_PARAMS, OPAMPS, CircuitParams, OpAmpSpec
 from repro.serving.engine import AdmissionQueue
@@ -571,6 +576,8 @@ class SolveService:
             "device_assembled": 0,
             "cross_branches": 0,
             "settle_steps_swept": 0,
+            "settle_chunks_resident": 0,
+            "settle_chunks_tiled": 0,
         }
 
     @staticmethod
@@ -1129,6 +1136,7 @@ class SolveService:
         if not popped:
             return {}
         assembled_before = engine.DC_STATS["device_assembled"]
+        chunks_before = dict(ops.DENSE_SWEEP_CHUNKS)
         # every span opened below, in core/ too, lands on this service
         with self._spans.installed(), span("serve.drain"):
             out: dict[int, SolveResult | SolveError] = {}
@@ -1227,6 +1235,10 @@ class SolveService:
                 self._counters["device_assembled"] += (
                     engine.DC_STATS["device_assembled"] - assembled_before
                 )
+                for path, before in chunks_before.items():
+                    self._counters[f"settle_chunks_{path}"] += (
+                        ops.DENSE_SWEEP_CHUNKS[path] - before
+                    )
         return out
 
     # ----------------------------------------------------------- sessions
@@ -1266,12 +1278,14 @@ class SolveService:
         ``{name: {"count": int, "s": float}}``.  ``queue_wait_s`` sums
         each dispatched ticket's wait from ``submit`` to the start of
         its first ``serve.dispatch``.  ``neg_cells`` /
-        ``cross_branches`` / ``settle_steps_swept`` are the circuit
-        counters of the module docstring.  ``device_assembled`` counts
-        the analog DC micro-batches whose operator was built on the
-        device (:func:`repro.core.engine.assemble_batch_device`) during
-        this service's drains: every analog DC dispatch, the inner
-        passes of graded refinement included.  ``pattern_derivations``
+        ``cross_branches`` / ``settle_steps_swept`` /
+        ``settle_chunks_resident`` / ``settle_chunks_tiled`` are the
+        circuit and sweep counters of the module docstring.
+        ``device_assembled`` counts the analog DC micro-batches whose
+        operator was built on the device
+        (:func:`repro.core.engine.assemble_batch_device`) during this
+        service's drains: every analog DC dispatch, the inner passes of
+        graded refinement included.  ``pattern_derivations``
         counts ``pattern_union`` calls per bucket (1 proves the cache
         served every later micro-batch on every stream).
 
@@ -1334,6 +1348,8 @@ class SolveService:
             "device_assembled": c["device_assembled"],
             "cross_branches": c["cross_branches"],
             "settle_steps_swept": c["settle_steps_swept"],
+            "settle_chunks_resident": c["settle_chunks_resident"],
+            "settle_chunks_tiled": c["settle_chunks_tiled"],
             "devices": len(self.devices),
             "device_micro_batches": list(self._device_batches),
             "inflight_per_device": self.inflight_per_device,

@@ -1,7 +1,9 @@
 """The service's circuit counters: ``neg_cells`` and ``cross_branches``
 say which half of the 2n network a workload stamps, and
-``settle_steps_swept`` how many Euler steps the device ran; the
-``core.settle_prep`` span times the host work before the first chunk.
+``settle_steps_swept`` how many Euler steps the device ran, and
+``settle_chunks_resident`` / ``settle_chunks_tiled`` on which dense
+sweep; the ``core.settle_prep`` span times the host work before the
+first chunk.
 
 An M-matrix (the 2-D Poisson operator) stamps no crosspoint branch
 between the ``x`` and ``-x`` halves; the 3-D elasticity operator of
@@ -71,6 +73,13 @@ def test_settle_steps_swept_is_chunks_times_chunk_length(settled):
     spans = st["spans"]
     assert spans["core.sweep_chunk"]["count"] >= 2
     assert st["settle_steps_swept"] == 50 * spans["core.sweep_chunk"]["count"]
+
+
+def test_settle_chunks_count_each_dense_chunk_by_path(settled):
+    """Both operators fit VMEM: every chunk ran the resident sweep."""
+    _, _, _, st = settled
+    assert st["settle_chunks_resident"] == st["spans"]["core.sweep_chunk"]["count"]
+    assert st["settle_chunks_tiled"] == 0
 
 
 def test_settle_prep_once_per_settled_micro_batch(settled):

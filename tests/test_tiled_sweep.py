@@ -1,8 +1,8 @@
 """The tiled dense settle sweep: one compiled device program per chunk.
 
-Past ``ops.SWEEP_STATE_LIMIT`` states the dense operator no longer fits
-in VMEM and each Euler step is one launch of the tiled ``dense_step``
-kernel.  :func:`repro.kernels.transient_step.tiled_transient_sweep_pallas`
+Past ``ops.sweep_state_limit()`` states the dense operator no longer
+fits in VMEM and each Euler step is one launch of the tiled
+``dense_step`` kernel.  :func:`repro.kernels.transient_step.tiled_transient_sweep_pallas`
 loops those launches on the device.  These tests hold it to the
 per-step loop it replaced: the same kernel on the same values in the
 same order, so the same states and settle step counts.
@@ -33,8 +33,7 @@ def _operands(seed, bsz, n):
     return m, z, c
 
 
-def _per_step_sweep(m, z, c, *, n_steps, dt=1.0, interpret=None,
-                    m_transposed=False):
+def _per_step_sweep(m, z, c, *, n_steps, dt=1.0, interpret=None):
     """The tiled branch of ``ops.transient_sweep`` as it was: one
     ``transient_step_batched`` launch per step, then a ``dt = 0`` launch
     for the residual at the final state."""
@@ -82,7 +81,7 @@ def test_chunk_program_matches_per_step_reference(n):
 def test_tiled_transient_sweep_is_one_launch(monkeypatch):
     """``ops.transient_sweep`` past the fused limit counts one launch
     per call, whatever ``n_steps``."""
-    monkeypatch.setattr(ops, "SWEEP_STATE_LIMIT", 0)
+    monkeypatch.setattr(ops, "sweep_state_limit", lambda: 0)
     m, z, c = _operands(11, 2, 137)
     for n_steps in (1, 9):
         launches = dict(ops.KERNEL_STATS)
@@ -108,7 +107,7 @@ def test_euler_settle_on_tiled_path_matches_per_step_loop(monkeypatch):
         xs.append(x)
     x_ref = np.stack(xs)
     bss = engine.assemble_batch(nets)
-    monkeypatch.setattr(ops, "SWEEP_STATE_LIMIT", 0)
+    monkeypatch.setattr(ops, "sweep_state_limit", lambda: 0)
 
     steps, x_final, res, dt = engine.euler_settle_batch(
         bss, x_ref, max_steps=40_000, interpret=True)

@@ -22,8 +22,9 @@ BATCH = 8
 ELL_NZ = 16384           # n = 2048 sparse systems (nz = 8n)
 ELL_WIDTH = 35           # their ELL width (row degree 16)
 DC_NZ = 1536             # the n=192 analog_2n bucket
-DENSE_SWEEP_NZ = 1792    # ops.SWEEP_STATE_LIMIT, the largest fused size
-DENSE_STEP_NZ = 2048     # first padded size past the fused limit
+DENSE_SWEEP_NZ = 3712    # ops.sweep_state_limit() at 128 MiB, the largest resident size
+DENSE_STEP_NZ = 3840     # first padded size past the resident limit
+SETTLE_CELL_NZ = (2048, 2432)    # the settle cells: 16x16 Poisson, ex56 at ne = 4
 
 
 @pytest.fixture(scope="module")
@@ -78,19 +79,34 @@ def test_ell_step_kernel_compiles(one_chip):
     assert "tpu_custom_call" in text
 
 
-def test_dense_sweep_compiles_at_fused_limit(one_chip):
-    from repro.kernels.ops import SWEEP_STATE_LIMIT, sweep_backend
+def _resident_sweep_text(one_chip, nz):
     from repro.kernels.transient_step import transient_sweep_pallas
 
-    assert DENSE_SWEEP_NZ == SWEEP_STATE_LIMIT
-    assert sweep_backend(DENSE_SWEEP_NZ, None) == "dense"
-    state = (BATCH, 1, DENSE_SWEEP_NZ)
-    text = _compile(
-        lambda m, z, c: transient_sweep_pallas(m, z, c, n_steps=50),
-        _spec(one_chip, (BATCH, DENSE_SWEEP_NZ, DENSE_SWEEP_NZ), jnp.float32),
+    state = (BATCH, nz)
+    return _compile(
+        lambda m, z, c, n: transient_sweep_pallas(m, z, c, n),
+        _spec(one_chip, (BATCH, nz, nz), jnp.float32),
         _spec(one_chip, state, jnp.float32), _spec(one_chip, state, jnp.float32),
+        _spec(one_chip, (), jnp.int32),
     )
-    assert "tpu_custom_call" in text
+
+
+def test_dense_sweep_compiles_at_fused_limit(one_chip):
+    from repro.kernels.ops import sweep_backend, sweep_state_limit
+
+    assert DENSE_SWEEP_NZ == sweep_state_limit()
+    assert sweep_backend(DENSE_SWEEP_NZ, None) == "dense"
+    assert "tpu_custom_call" in _resident_sweep_text(one_chip, DENSE_SWEEP_NZ)
+
+
+@pytest.mark.parametrize("nz", SETTLE_CELL_NZ)
+def test_resident_sweep_compiles_at_cell_sizes(one_chip, nz):
+    """The settle cells' chunk program: the operator VMEM-resident, a
+    traced step count."""
+    from repro.kernels.ops import sweep_backend
+
+    assert sweep_backend(nz, None) == "dense"
+    assert "tpu_custom_call" in _resident_sweep_text(one_chip, nz)
 
 
 def test_dense_step_compiles(one_chip):
